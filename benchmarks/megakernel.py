@@ -9,8 +9,8 @@ Two halves of one optimisation story:
   the episode. On this CPU box only the interpret/XLA-twin rungs run, so the
   benchmark records the *equivalence* measurement (decision trajectory EXACT,
   float fields' max ulp vs the scan engine) and the roofline VMEM-fit plan,
-  not a compiled-TPU throughput number (that is the manual TPU smoke lane's
-  job — see .github/workflows/tpu-smoke.yml).
+  not a compiled-TPU throughput number (the megakernel does not compile
+  for the TPU yet).
 
 * **Host half — asynchronous chunk staging** (``core.episode.stream_chunks``):
   chunk k+1's host->device ``device_put`` now runs on a dedicated transfer
@@ -124,16 +124,18 @@ def _mega_equivalence(steps: int = 6) -> dict:
 
 
 def _vmem_fragment() -> dict:
-    """Roofline fit for the benchmark's fleet shape on a 16 MiB-VMEM core."""
+    """Roofline fit for the benchmark's fleet shape on the target chip."""
     from repro.core import DDPGConfig
     from repro.envs import LustreSimV2
-    from repro.roofline import episode_vmem_plan, suggest_max_capacity
+    from repro.roofline import (TARGET_DEVICE_KIND, episode_vmem_plan,
+                                suggest_max_capacity)
 
     env = LustreSimV2("seq_write").to_model_env()
     cfg = DDPGConfig.for_env(env, updates_per_step=96)
     capacity = 64  # MagpieAgent's default replay capacity
     kw = dict(steps=5, state_dim=cfg.state_dim, action_dim=cfg.action_dim,
-              hidden=cfg.hidden, num_updates=96, batch_size=cfg.batch_size)
+              hidden=cfg.hidden, num_updates=96, batch_size=cfg.batch_size,
+              device_kind=TARGET_DEVICE_KIND)
     from repro.kernels.ddpg_fused import packed_dims
     pad = packed_dims(cfg.state_dim, cfg.action_dim, cfg.hidden).pad
     plan = episode_vmem_plan(capacity=capacity, pad=pad, **kw)

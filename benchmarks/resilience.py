@@ -230,7 +230,8 @@ def measure_quarantine(quick: bool = False) -> dict:
     repeats = 2 if quick else 3
 
     chaos = ChaosConfig(fail_chunks=((1, 99),))   # chunk 1 never stages
-    sup = ChunkSupervisor(max_retries=1, backoff_seconds=0.0)
+    sup = ChunkSupervisor(max_retries=1, backoff_seconds=0.0,
+                          on_failure="skip")
     chaotic = FleetService(chunk=2, warmup_steps=WARMUP, eval_runs=1,
                            supervisor=sup, chaos=chaos.host())
     for seed in range(4):

@@ -53,15 +53,15 @@ def main() -> None:
                         "streamed fleet (> 1 implies the scan engine)")
     parser.add_argument("--chunk", type=int, default=None,
                         help="sessions per streamed chunk (fleet mode)")
-    parser.add_argument("--compile-cache", nargs="?", const="", default=None,
-                        metavar="DIR",
+    parser.add_argument("--compile-cache", action="store_true",
                         help="enable JAX's persistent compilation cache "
-                        "(optional DIR; default ~/.cache/repro-jax-cache)")
+                        "($JAX_COMPILATION_CACHE_DIR if set, else "
+                        "<repo>/.jax_cache)")
     args = parser.parse_args()
 
-    if args.compile_cache is not None:
+    if args.compile_cache:
         from repro.core import enable_persistent_compilation_cache
-        path = enable_persistent_compilation_cache(args.compile_cache or None)
+        path = enable_persistent_compilation_cache()
         print(f"persistent compilation cache: {path}")
 
     if args.sessions > 1:

@@ -244,10 +244,10 @@ def main() -> None:
                         "default: one chunk of the whole fleet")
     parser.add_argument("--steps", type=int, default=30,
                         help="tuning steps per session (paper budget: 30)")
-    parser.add_argument("--compile-cache", nargs="?", const="", default=None,
-                        metavar="DIR",
+    parser.add_argument("--compile-cache", action="store_true",
                         help="enable JAX's persistent compilation cache "
-                        "(optional DIR; default ~/.cache/repro-jax-cache)")
+                        "($JAX_COMPILATION_CACHE_DIR if set, else "
+                        "<repo>/.jax_cache)")
     parser.add_argument("--service", action="store_true",
                         help="run through the persistent FleetService "
                         "(leased slots, advance() rounds)")
@@ -296,9 +296,9 @@ def main() -> None:
                         "parameter averages")
     args = parser.parse_args()
 
-    if args.compile_cache is not None:
+    if args.compile_cache:
         from repro.core import enable_persistent_compilation_cache
-        path = enable_persistent_compilation_cache(args.compile_cache or None)
+        path = enable_persistent_compilation_cache()
         print(f"persistent compilation cache: {path}")
 
     if args.service or args.resume:

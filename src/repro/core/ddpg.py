@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import optim
+from repro.kernels.ddpg_fused import PRECISION
 
 
 # ---------------------------------------------------------------------------
@@ -39,9 +40,10 @@ def mlp_init(key: jax.Array, sizes: Sequence[int]) -> list:
 
 
 def mlp_apply(params: list, x: jnp.ndarray) -> jnp.ndarray:
-    """ReLU MLP; no activation on the final layer (callers add their own)."""
+    """ReLU MLP; no activation on the final layer (callers add their own).
+    Matmuls run at the learner's explicit precision (``PRECISION``)."""
     for i, layer in enumerate(params):
-        x = x @ layer["w"] + layer["b"]
+        x = jnp.matmul(x, layer["w"], precision=PRECISION) + layer["b"]
         if i + 1 < len(params):
             x = jax.nn.relu(x)
     return x

@@ -586,18 +586,14 @@ def _build_cell_fleet_episode(step_fn, space, cfg, actor_tx, critic_tx,
     gmapped = jax.vmap(cell, in_axes=(0, 0, 0, 0, 0, (0, 0, 0, 0, 0)))
     if devices is not None and len(devices) > 1:
         from jax.sharding import Mesh, PartitionSpec as P
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:  # newer jax
-            from jax import shard_map
         mesh = Mesh(np.array(devices), ("session",))
-        gmapped = shard_map(
+        gmapped = jax.shard_map(
             gmapped, mesh=mesh,
             in_specs=(P("session"), P("session"), P("session"),
                       P("session"), P("session"),
                       (P("session"), P("session"), P("session"),
                        P("session"), P("session"))),
-            out_specs=P("session"), check_rep=False)
+            out_specs=P("session"), check_vma=False)
 
     def episode(params, w_vec, lo, span, carry, xs):
         health = None
@@ -769,6 +765,12 @@ def _build_mega_episode(step_fn, space: ParamSpace, cfg: DDPGConfig,
 
 _EPISODE_CACHE: dict = {}
 
+# the persistent compilation cache's home when JAX_COMPILATION_CACHE_DIR is
+# unset: inside the checkout (``<repo>/.jax_cache``, listed in .gitignore)
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
 
 def _compiled_episode(step_fn, space, cfg, actor_tx, critic_tx, learn,
                       num_updates, fleet: bool, devices: Optional[tuple],
@@ -881,17 +883,13 @@ def _compiled_episode(step_fn, space, cfg, actor_tx, critic_tx, learn,
         episode = jax.vmap(episode, in_axes=(0, 0, 0, 0, 0, (0, 0, 0)))
         if devices is not None and len(devices) > 1:
             from jax.sharding import Mesh, PartitionSpec as P
-            try:
-                from jax.experimental.shard_map import shard_map
-            except ImportError:  # newer jax
-                from jax import shard_map
             mesh = Mesh(np.array(devices), ("session",))
-            episode = shard_map(
+            episode = jax.shard_map(
                 episode, mesh=mesh,
                 in_specs=(P("session"), P("session"), P("session"),
                           P("session"), P("session"),
                           (P("session"), P("session"), P("session"))),
-                out_specs=P("session"), check_rep=False)
+                out_specs=P("session"), check_vma=False)
     # Donating the carry (learner params + opt state + FIFO storage — the
     # bulk of the program's operands) lets XLA reuse those buffers in place
     # instead of defensively copying them across the call boundary. Callers
@@ -1605,12 +1603,25 @@ def precompile_fleet_episode(env, agent, steps: int, sessions: int,
     with ``enable_persistent_compilation_cache`` active, later processes
     hit the on-disk cache. Agent, env and every RNG stream are untouched.
     Returns the jitted episode program."""
-    model = env.model
-    space = env.param_space
-    cfg = agent.cfg
     devices = tuple(devices) if devices else None
     ndev = len(devices) if devices else 1
     c = resolve_chunk(sessions, chunk, ndev)
+    fn = _compiled_episode(env.model.step_fn, env.param_space, agent.cfg,
+                           agent._actor_tx, agent._critic_tx, learn,
+                           agent.cfg.updates_per_step,
+                           fleet=True, devices=devices, policy=policy)
+    outs = fn(*chunk_operands(env, agent, steps, c, policy=policy))
+    jax.block_until_ready(outs)
+    return fn
+
+
+def chunk_operands(env, agent, steps: int, c: int, policy=None) -> tuple:
+    """The fleet episode program's operands for one chunk of ``c`` sessions
+    (throwaway copies of session 0's state, zero exploration): what
+    ``precompile_fleet_episode`` runs, and — under ``jax.eval_shape`` — the
+    shapes a chunk program is compiled for."""
+    model = env.model
+    cfg = agent.cfg
 
     def tile(x):
         x = np.asarray(x)
@@ -1648,14 +1659,8 @@ def precompile_fleet_episode(env, agent, steps: int, sessions: int,
             rollbacks=jnp.zeros((c,), jnp.int32)))
     xs = (jnp.zeros((c, steps), bool), jnp.zeros((c, steps, m), jnp.float32),
           jnp.zeros((c, steps, m), jnp.float32))
-
-    fn = _compiled_episode(model.step_fn, space, cfg, agent._actor_tx,
-                           agent._critic_tx, learn, cfg.updates_per_step,
-                           fleet=True, devices=devices, policy=policy)
-    outs = fn(jax.tree_util.tree_map(tile, model.params),
-              tile(np.zeros(k, np.float32)), tile(lo), tile(span), carry, xs)
-    jax.block_until_ready(outs)
-    return fn
+    return (jax.tree_util.tree_map(tile, model.params),
+            tile(np.zeros(k, np.float32)), tile(lo), tile(span), carry, xs)
 
 
 def episode_cache_stats() -> dict:
@@ -1669,19 +1674,22 @@ def episode_cache_stats() -> dict:
     }
 
 
-def enable_persistent_compilation_cache(path: Optional[str] = None) -> str:
-    """Point JAX's persistent compilation cache at ``path`` (default:
-    ``$REPRO_COMPILE_CACHE_DIR`` or ``~/.cache/repro-jax-cache``).
+def enable_persistent_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for this process.
 
-    Repeated processes — grid sweeps, back-to-back example runs, CI lanes —
-    then deserialize the episode executable instead of recompiling it.
-    Call BEFORE the first compilation of the process (compiles that already
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and the directory is left alone; otherwise the cache goes to the
+    fixed in-checkout directory ``COMPILE_CACHE_DIR`` (the directory is part
+    of each entry's key, so it must not move between processes). Repeated
+    processes — grid sweeps, back-to-back example runs, CI lanes — then
+    deserialize the episode executable instead of recompiling it. Call
+    BEFORE the first compilation of the process (compiles that already
     happened are not retro-cached). Returns the cache directory."""
-    path = (path or os.environ.get("REPRO_COMPILE_CACHE_DIR")
-            or os.path.join(os.path.expanduser("~"), ".cache",
-                            "repro-jax-cache"))
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache everything: the episode program is worth persisting no matter
     # how quickly this particular box compiled it
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

@@ -184,10 +184,12 @@ class FleetService:
         # bitwise (and by executable identity) the plain service
         self.resilience = resilience
         # host-side chunk supervision: retries are bitwise-invisible on
-        # success; a chunk that keeps failing is SKIPPED and its sessions
-        # quarantined through the leave path at the next boundary (the
-        # supervisor's on_failure is forced to "skip" inside advance —
-        # a persistent service must survive, not crash)
+        # success; a chunk that keeps failing raises ``ChunkFailure``
+        # (on_failure="raise", the default) or, with on_failure="skip", is
+        # SKIPPED and its sessions quarantined through the leave path at
+        # the next boundary — only when the operator asked for it, so a
+        # chunk program that cannot run (e.g. does not compile) is never
+        # hidden behind a quarantine
         self.supervisor = supervisor
         self.chaos = chaos
         # service-wide SharingConfig (core.sharing); None = off, bitwise
@@ -485,8 +487,9 @@ class FleetService:
         and their results are discarded — so a ragged cell runs the same
         fixed-shape cell program as a full one.
 
-        Returns the sids to QUARANTINE: with a ``ChunkSupervisor``, a chunk
-        that exhausts its retries is skipped — its rows' host state is
+        Returns the sids to QUARANTINE: with a ``ChunkSupervisor`` whose
+        ``on_failure`` is ``"skip"``, a chunk that exhausts its retries is
+        skipped — its rows' host state is
         untouched (the drain never ran) and its sessions are handed back to
         ``advance`` for the leave path. The chunk schedule is pure
         scheduling, so skipping chunk i never perturbs chunk j."""
@@ -730,16 +733,11 @@ class FleetService:
                 sizes[a:b] = np.asarray(carry.buffer.size)[:cnt]
             learn_keys[a:b] = np.asarray(carry.learn_key)[:cnt]
 
-        sup = self.supervisor
-        if sup is not None and sup.on_failure != "skip":
-            # a persistent service must survive a dead chunk: quarantine,
-            # never crash (see __init__)
-            sup = sup._replace(on_failure="skip")
         staging_stats: dict = {}
         stream_stats = stream_chunks(
             lambda args: fn(*args), stage, drain, num_chunks,
-            overlap=self.overlap, supervisor=sup, chaos=self.chaos,
-            staging=staging_stats)
+            overlap=self.overlap, supervisor=self.supervisor,
+            chaos=self.chaos, staging=staging_stats)
         wall = time.perf_counter() - t0
         failed_rows: set = set()
         quarantined: list = []

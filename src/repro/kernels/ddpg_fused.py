@@ -57,12 +57,19 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _ADAM_B1 = 0.9
 _ADAM_B2 = 0.999
 _ADAM_EPS = 1e-8
 NUM_NETS = 4      # actor, critic, actor_targ, critic_targ
 NUM_LAYERS = 3    # two hidden layers + head (the paper's MLPs)
+# Every learner matmul runs at full float32 precision, in the kernel and in
+# every XLA formulation alike (``core.ddpg.mlp_apply`` uses this constant):
+# XLA on TPU otherwise rounds f32 dot operands to bfloat16, which would make
+# the kernel and the XLA learner disagree by far more than f32 rounding. The
+# CPU backend always computes f32 dots exactly, so CPU results are unchanged.
+PRECISION = jax.lax.Precision.HIGHEST
 
 
 class PackedDims(NamedTuple):
@@ -167,6 +174,15 @@ def pack_minibatches(batches, dims: PackedDims):
     return sx, cx, s2x, r
 
 
+def reward_lanes(r, pad: int):
+    """Rewards ``[..., B]`` -> ``[..., B, pad]`` tiles with the reward in
+    lane 0 and exact zeros elsewhere: the layout of the critic's output
+    tile (see ``_critic_fwd``), so the Bellman target is built without
+    slicing a single lane out of a tile."""
+    lane0 = jnp.arange(pad) == 0
+    return jnp.where(lane0, r[..., None], jnp.float32(0.0))
+
+
 # ---------------------------------------------------------------------------
 # The packed update step (shared by the kernel body and the XLA twin)
 # ---------------------------------------------------------------------------
@@ -176,9 +192,9 @@ def _mlp_fwd(w, b, x):
     point of the trunk: relu(0 @ W + 0) = 0 on every padded lane."""
     h = x
     for i in range(NUM_LAYERS - 1):
-        h = jax.nn.relu(jnp.dot(h, w[i], preferred_element_type=jnp.float32)
-                        + b[i])
-    return jnp.dot(h, w[NUM_LAYERS - 1],
+        h = jax.nn.relu(jnp.dot(h, w[i], precision=PRECISION,
+                                preferred_element_type=jnp.float32) + b[i])
+    return jnp.dot(h, w[NUM_LAYERS - 1], precision=PRECISION,
                    preferred_element_type=jnp.float32) + b[NUM_LAYERS - 1]
 
 
@@ -189,7 +205,16 @@ def _actor_fwd(w, b, x, act_mask):
 
 
 def _critic_fwd(w, b, x):
-    return _mlp_fwd(w, b, x)[:, 0]
+    """Q as a ``[B, P]`` tile: lane 0 holds Q and every other lane is an
+    exact zero (the head's padded columns and bias are zero). Losses reduce
+    the whole tile (``_batch_mean``): a one-lane column slice cannot be
+    relaid out by the TPU compiler (``Offset change``)."""
+    return _mlp_fwd(w, b, x)
+
+
+def _batch_mean(tile):
+    """Mean over the batch rows of a lane-0 tile (``_critic_fwd``)."""
+    return jnp.sum(tile) / tile.shape[0]
 
 
 def _adam(count, mu_w, mu_b, nu_w, nu_b, gw, gb, w, b, lr):
@@ -226,21 +251,22 @@ def packed_update(carry, batch, dims: PackedDims, gamma, tau,
     ``core.ddpg._ddpg_step``, on [P, P]-blocked tensors.
 
     ``carry`` = (weights [4,L,P,P], biases [4,L,P], mom_w [2,2,L,P,P],
-    mom_b [2,2,L,P], counts [2] i32); ``batch`` = (sx, cx, s2x, r) for one
-    minibatch. Returns (carry, (critic_loss, actor_loss, q_mean)).
+    mom_b [2,2,L,P], counts [2] i32); ``batch`` = (sx, cx, s2x, rx) for one
+    minibatch, the rewards as ``reward_lanes`` tiles. Returns (carry,
+    (critic_loss, actor_loss, q_mean)).
     """
     weights, biases, mom_w, mom_b, counts = carry
-    sx, cx, s2x, r = batch
+    sx, cx, s2x, rx = batch
 
     # --- critic: Bellman regression against the frozen targets -------------
     a2 = _actor_fwd(weights[2], biases[2], s2x, act_mask)
     c2x = _place_actions(s2x, a2, dims)
     q_targ = jax.lax.stop_gradient(
-        r + gamma * _critic_fwd(weights[3], biases[3], c2x))
+        rx + gamma * _critic_fwd(weights[3], biases[3], c2x))
 
     def critic_loss_fn(wb):
         w, b = wb
-        return jnp.mean(jnp.square(_critic_fwd(w, b, cx) - q_targ))
+        return _batch_mean(jnp.square(_critic_fwd(w, b, cx) - q_targ))
 
     critic_loss, (gcw, gcb) = jax.value_and_grad(critic_loss_fn)(
         (weights[1], biases[1]))
@@ -252,7 +278,7 @@ def packed_update(carry, batch, dims: PackedDims, gamma, tau,
     def actor_loss_fn(wb):
         w, b = wb
         mu = _actor_fwd(w, b, sx, act_mask)
-        return -jnp.mean(_critic_fwd(cw, cb, _place_actions(sx, mu, dims)))
+        return -_batch_mean(_critic_fwd(cw, cb, _place_actions(sx, mu, dims)))
 
     actor_loss, (gaw, gab) = jax.value_and_grad(actor_loss_fn)(
         (weights[0], biases[0]))
@@ -265,7 +291,7 @@ def packed_update(carry, batch, dims: PackedDims, gamma, tau,
     atb = (1 - tau) * biases[2] + tau * ab
     ctw = (1 - tau) * weights[3] + tau * cw
     ctb = (1 - tau) * biases[3] + tau * cb
-    q_mean = jnp.mean(_critic_fwd(cw, cb, cx))
+    q_mean = _batch_mean(_critic_fwd(cw, cb, cx))
 
     carry = (jnp.stack([aw, cw, atw, ctw]), jnp.stack([ab, cb, atb, ctb]),
              jnp.stack([jnp.stack([amu_w, anu_w]),
@@ -282,35 +308,38 @@ def packed_update(carry, batch, dims: PackedDims, gamma, tau,
 
 def _ddpg_kernel(dims: PackedDims, gamma, tau, actor_lr, critic_lr,
                  num_updates: int,
-                 sx_ref, cx_ref, s2x_ref, r_ref,
-                 w_ref, b_ref, mw_ref, mb_ref, cnt_ref,
-                 ow_ref, ob_ref, omw_ref, omb_ref, ocnt_ref, met_ref):
+                 cnt_ref, sx_ref, cx_ref, s2x_ref, rx_ref,
+                 w_ref, b_ref, mw_ref, mb_ref,
+                 ow_ref, ob_ref, omw_ref, omb_ref, met_ref):
     act_mask = (jax.lax.broadcasted_iota(jnp.int32, (1, dims.pad), 1)
                 < dims.action_dim).astype(jnp.float32)
-    # load once: all four parameter sets + both moment sets stay in VMEM for
-    # the whole loop — nothing round-trips between the 96 updates
-    params = (w_ref[0], b_ref[0], mw_ref[0], mb_ref[0], cnt_ref[0])
-    met0 = jnp.zeros((num_updates, 3), jnp.float32)
-    sx, cx, s2x, r = sx_ref[0], cx_ref[0], s2x_ref[0], r_ref[0]
+    # the learner state lives in the output blocks for the whole loop: all
+    # four parameter sets + both moment sets stay in VMEM, nothing
+    # round-trips to HBM between the 96 updates
+    ow_ref[...] = w_ref[...]
+    ob_ref[...] = b_ref[...]
+    omw_ref[...] = mw_ref[...]
+    omb_ref[...] = mb_ref[...]
 
     def body(u, carry):
-        params, met = carry
-        batch = tuple(jax.lax.dynamic_index_in_dim(t, u, 0, keepdims=False)
-                      for t in (sx, cx, s2x, r))
-        params, (cl, al, qm) = packed_update(
+        # Adam counts before update u (SMEM scalars), as (1, 1) vectors: the
+        # bias corrections need a vector power, the TPU has no scalar one
+        counts = tuple(jnp.full((1, 1), cnt_ref[0, 0, j] + u, jnp.int32)
+                       for j in range(2))
+        params = (ow_ref[0], ob_ref[0], omw_ref[0], omb_ref[0], counts)
+        # the minibatch is read by indexing the refs, not by slicing values
+        batch = (sx_ref[0, u], cx_ref[0, u], s2x_ref[0, u], rx_ref[0, u])
+        (w, b, mw, mb, _), met = packed_update(
             params, batch, dims, gamma, tau, actor_lr, critic_lr, act_mask)
-        met = jax.lax.dynamic_update_index_in_dim(
-            met, jnp.stack([cl, al, qm]), u, 0)
-        return params, met
+        ow_ref[0] = w
+        ob_ref[0] = b
+        omw_ref[0] = mw
+        omb_ref[0] = mb
+        for j, v in enumerate(met):
+            met_ref[0, 0, 3 * u + j] = v
+        return carry
 
-    (weights, biases, mom_w, mom_b, counts), met = jax.lax.fori_loop(
-        0, num_updates, body, (params, met0))
-    ow_ref[0] = weights
-    ob_ref[0] = biases
-    omw_ref[0] = mom_w
-    omb_ref[0] = mom_b
-    ocnt_ref[0] = counts
-    met_ref[0] = met
+    jax.lax.fori_loop(0, num_updates, body, None)
 
 
 def ddpg_fused_learn(packed, batches, *, dims: PackedDims, gamma: float,
@@ -324,30 +353,38 @@ def ddpg_fused_learn(packed, batches, *, dims: PackedDims, gamma: float,
     learner runs as an independent program instance. Returns (packed',
     metrics dict of [N, U] arrays). Parameter inputs are aliased to the
     outputs — callers must treat ``packed`` as consumed.
+
+    Layout for the TPU compiler: Adam counts and per-update metrics are
+    scalars, so they live in SMEM; rewards enter as ``reward_lanes`` tiles.
+    Each update adds one to both counts, so the counts out are computed
+    here, exactly.
     """
     weights, biases, mom_w, mom_b, counts = packed
     sx, cx, s2x, r = batches
     n, u = sx.shape[0], sx.shape[1]
     p = dims.pad
+    rx = reward_lanes(r, p)
+    cnt = counts.reshape(n, 1, 2)
 
-    def bspec(shape):
+    def bspec(shape, **kw):
         nd = len(shape)
-        return pl.BlockSpec((1, *shape), lambda i, nd=nd: (i,) + (0,) * nd)
+        return pl.BlockSpec((1, *shape), lambda i, nd=nd: (i,) + (0,) * nd,
+                            **kw)
 
-    in_specs = [bspec(sx.shape[1:]), bspec(cx.shape[1:]),
-                bspec(s2x.shape[1:]), bspec(r.shape[1:]),
+    smem = {"memory_space": pltpu.SMEM}
+    in_specs = [bspec(cnt.shape[1:], **smem),
+                bspec(sx.shape[1:]), bspec(cx.shape[1:]),
+                bspec(s2x.shape[1:]), bspec(rx.shape[1:]),
                 bspec(weights.shape[1:]), bspec(biases.shape[1:]),
-                bspec(mom_w.shape[1:]), bspec(mom_b.shape[1:]),
-                bspec(counts.shape[1:])]
+                bspec(mom_w.shape[1:]), bspec(mom_b.shape[1:])]
     out_specs = [bspec(weights.shape[1:]), bspec(biases.shape[1:]),
                  bspec(mom_w.shape[1:]), bspec(mom_b.shape[1:]),
-                 bspec(counts.shape[1:]), bspec((u, 3))]
+                 bspec((1, 3 * u), **smem)]
     out_shape = [jax.ShapeDtypeStruct(weights.shape, jnp.float32),
                  jax.ShapeDtypeStruct(biases.shape, jnp.float32),
                  jax.ShapeDtypeStruct(mom_w.shape, jnp.float32),
                  jax.ShapeDtypeStruct(mom_b.shape, jnp.float32),
-                 jax.ShapeDtypeStruct(counts.shape, jnp.int32),
-                 jax.ShapeDtypeStruct((n, u, 3), jnp.float32)]
+                 jax.ShapeDtypeStruct((n, 1, 3 * u), jnp.float32)]
     # rough cost: fwd+bwd over 5 network passes per update (helps scheduling)
     gemm_flops = 2 * sx.shape[2] * p * p * NUM_LAYERS
     cost = pl.CostEstimate(flops=int(n * u * 15 * gemm_flops),
@@ -355,19 +392,20 @@ def ddpg_fused_learn(packed, batches, *, dims: PackedDims, gamma: float,
                            transcendentals=int(n * u * sx.shape[2] * p * 2))
     kernel = functools.partial(_ddpg_kernel, dims, gamma, tau, actor_lr,
                                critic_lr, u)
-    ow, ob, omw, omb, ocnt, met = pl.pallas_call(
+    ow, ob, omw, omb, met = pl.pallas_call(
         kernel,
         grid=(n,),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        input_output_aliases={4: 0, 5: 1, 6: 2, 7: 3, 8: 4},
+        input_output_aliases={5: 0, 6: 1, 7: 2, 8: 3},
         cost_estimate=cost,
         interpret=interpret,
-    )(sx, cx, s2x, r, weights, biases, mom_w, mom_b, counts)
+    )(cnt, sx, cx, s2x, rx, weights, biases, mom_w, mom_b)
+    met = met.reshape(n, u, 3)
     metrics = {"critic_loss": met[..., 0], "actor_loss": met[..., 1],
                "q_mean": met[..., 2]}
-    return (ow, ob, omw, omb, ocnt), metrics
+    return (ow, ob, omw, omb, counts + u), metrics
 
 
 # ---------------------------------------------------------------------------
@@ -390,6 +428,8 @@ def ddpg_fused_xla(packed, batches, *, dims: PackedDims, gamma: float,
             return c, jnp.stack([cl, al, qm])
         return jax.lax.scan(body, carry, batch)
 
+    sx, cx, s2x, r = batches
+    batches = (sx, cx, s2x, reward_lanes(r, dims.pad))
     packed, met = jax.vmap(one_session)(packed, batches)
     metrics = {"critic_loss": met[..., 0], "actor_loss": met[..., 1],
                "q_mean": met[..., 2]}

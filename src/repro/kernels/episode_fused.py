@@ -53,8 +53,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.ddpg_fused import (NUM_LAYERS, PackedDims, _unpack_net,
-                                      pack_minibatches, packed_update)
+from repro.kernels.ddpg_fused import (NUM_LAYERS, PRECISION, PackedDims,
+                                      _unpack_net,
+                                      pack_minibatches, packed_update,
+                                      reward_lanes)
 
 
 class EpisodeKernelSpec(NamedTuple):
@@ -165,9 +167,12 @@ def _episode_body(spec: EpisodeKernelSpec, op: EpisodeOperands,
         actor_p, sv = bar((actor_p, state_vec))
         h = sv
         for li in range(NUM_LAYERS - 1):
-            h = jax.nn.relu(h @ actor_p[li]["w"] + actor_p[li]["b"])
-        policy = bar(jax.nn.sigmoid(h @ actor_p[NUM_LAYERS - 1]["w"]
-                                    + actor_p[NUM_LAYERS - 1]["b"]))
+            h = jax.nn.relu(jnp.matmul(h, actor_p[li]["w"],
+                                       precision=PRECISION)
+                            + actor_p[li]["b"])
+        policy = bar(jax.nn.sigmoid(
+            jnp.matmul(h, actor_p[NUM_LAYERS - 1]["w"], precision=PRECISION)
+            + actor_p[NUM_LAYERS - 1]["b"]))
         explored = jnp.clip(policy + noise, 0.0, 1.0)
         action = jnp.where(use_warmup, jnp.clip(warmup_a, 0.0, 1.0),
                            explored)
@@ -208,12 +213,13 @@ def _episode_body(spec: EpisodeKernelSpec, op: EpisodeOperands,
             batches = gather_minibatches(tuple(buf_in[:4]), idx)
             batches = tuple(b.astype(jnp.float32) for b in batches)
             sx, cx, s2x, r = pack_minibatches(batches, dims)
+            rx = reward_lanes(r, dims.pad)
 
             def upd(u, ucarry):
                 pk, met = ucarry
                 batch = tuple(
                     jax.lax.dynamic_index_in_dim(x, u, 0, keepdims=False)
-                    for x in (sx, cx, s2x, r))
+                    for x in (sx, cx, s2x, rx))
                 pk, (cl, al, qm) = packed_update(
                     pk, batch, dims, cfg.gamma, cfg.tau, cfg.actor_lr,
                     cfg.critic_lr, act_mask)
@@ -274,7 +280,7 @@ def episode_fused_learn(operands: EpisodeOperands, *,
     them as consumed. Raises ``ValueError`` (via the roofline VMEM-fit
     check) before building an oversized kernel.
     """
-    from repro.roofline.vmem import check_episode_vmem_fit
+    from repro.roofline.vmem import TARGET_DEVICE_KIND, check_episode_vmem_fit
 
     n, T = operands.use_warmup.shape
     capacity = operands.buffer[0].shape[1]
@@ -284,6 +290,8 @@ def episode_fused_learn(operands: EpisodeOperands, *,
         action_dim=spec.cfg.action_dim, hidden=spec.cfg.hidden,
         num_updates=spec.num_updates if spec.learn else 0,
         batch_size=spec.cfg.batch_size, pad=spec.dims.pad,
+        device_kind=(TARGET_DEVICE_KIND if interpret
+                     else jax.devices()[0].device_kind),
         env_state_bytes=env_bytes)
 
     flat_in, in_tree = jax.tree_util.tree_flatten(operands)

@@ -30,11 +30,15 @@ from repro.kernels.mamba2_scan import ssd_scan as _ssd_scan
 from repro.kernels.rwkv6 import wkv6_scan as _wkv6_scan
 
 
+def auto_mode(platform: str) -> str:
+    """What ``REPRO_KERNELS=auto`` (the default) picks on ``platform``: the
+    compiled Pallas kernels on a TPU, the XLA paths elsewhere."""
+    return "pallas" if platform == "tpu" else "xla"
+
+
 def _mode() -> str:
     m = os.environ.get("REPRO_KERNELS", "auto")
-    if m == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "xla"
-    return m
+    return auto_mode(jax.default_backend()) if m == "auto" else m
 
 
 # ---------------------------------------------------------------------------

@@ -19,11 +19,28 @@ from __future__ import annotations
 
 import math
 
-# Per-core VMEM on current TPUs (v4/v5e/v5p all carry 16 MiB per core
-# except v5p's 32; the conservative floor is the portable budget). Pallas
-# double-buffers HBM<->VMEM block copies, which the pipeline factor covers.
-VMEM_BYTES = 16 * 2 ** 20
+# VMEM a Pallas kernel's pipelined blocks may use under the compiler's
+# default limits, keyed by ``device_kind`` as JAX reports it. TPU v5e
+# ("TPU v5 lite"): compiling for a described v5e chip, four double-buffered
+# 4 MiB blocks (16 MiB) are accepted and 4 MiB + 16 KiB each are refused;
+# the chip has 128 MiB of VMEM in all. Pallas double-buffers HBM<->VMEM
+# block copies, which the pipeline factor covers.
+VMEM_BYTES = {"TPU v5 lite": 16 * 2 ** 20}
+# what an interpret-mode kernel (no chip) is checked against: the v5e this
+# repository runs on
+TARGET_DEVICE_KIND = "TPU v5 lite"
 _PIPELINE_FACTOR = 2
+
+
+def vmem_budget(device_kind: str) -> int:
+    """The VMEM budget of ``device_kind``; an unknown device raises."""
+    try:
+        return VMEM_BYTES[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no VMEM budget is known for device kind {device_kind!r} "
+            f"(known: {sorted(VMEM_BYTES)}); measure it with the device's "
+            f"compiler and add it to roofline.vmem.VMEM_BYTES") from None
 
 # fields of the packed learner layout (kernels.ddpg_fused.pack_params):
 # weights [4,L,P,P] + biases [4,L,P] + mom_w [2,2,L,P,P] + mom_b [2,2,L,P]
@@ -32,14 +49,17 @@ _NUM_LAYERS = 3
 
 
 def episode_vmem_plan(*, steps, capacity, state_dim, action_dim, hidden,
-                      num_updates, batch_size, pad, env_state_bytes=0):
-    """Byte budget of ONE megakernel grid instance (one session's episode).
+                      num_updates, batch_size, pad, device_kind,
+                      env_state_bytes=0):
+    """Byte budget of ONE megakernel grid instance (one session's episode)
+    against the VMEM of ``device_kind``.
 
     Returns ``{"contributions": {name: bytes}, "per_session_bytes",
     "pipelined_bytes", "budget_bytes", "fits"}``. ``pad`` is the packed
     lane width P from ``kernels.ddpg_fused.packed_dims``;
     ``env_state_bytes`` the flattened env-state leaf bytes per session.
     """
+    budget = vmem_budget(device_kind)
     P = int(pad)
     L = _NUM_LAYERS
     k, m = int(state_dim), int(action_dim)
@@ -51,9 +71,9 @@ def episode_vmem_plan(*, steps, capacity, state_dim, action_dim, hidden,
                           + (4 + 4) * L * P * 4 + 2 * 4,
         # FIFO window: s [cap,k], a [cap,m], r [cap], s2 [cap,k]
         "replay_window": int(capacity) * (2 * k + m + 1) * 4,
-        # gathered minibatches packed to P lanes: sx/cx/s2x [U,B,P] + r [U,B]
+        # gathered minibatches packed to P lanes: sx/cx/s2x/rx [U,B,P]
         "minibatch_workspace": int(num_updates) * int(batch_size)
-                               * (3 * P + 1) * 4,
+                               * 4 * P * 4,
         # trace: action_idx [T,m] i32 + metrics [T,k] + rewards/objectives
         # f32 + restarts i32
         "trace": int(steps) * (m * 4 + k * 4 + 12),
@@ -67,8 +87,8 @@ def episode_vmem_plan(*, steps, capacity, state_dim, action_dim, hidden,
         "contributions": contributions,
         "per_session_bytes": per_session,
         "pipelined_bytes": pipelined,
-        "budget_bytes": VMEM_BYTES,
-        "fits": pipelined <= VMEM_BYTES,
+        "budget_bytes": budget,
+        "fits": pipelined <= budget,
     }
 
 
@@ -83,15 +103,16 @@ _REMEDIES = {
 
 
 def check_episode_vmem_fit(*, chunk, steps, capacity, state_dim, action_dim,
-                           hidden, num_updates, batch_size, pad,
-                           env_state_bytes=0, budget_bytes=None):
+                           hidden, num_updates, batch_size, pad, device_kind,
+                           env_state_bytes=0):
     """Raise ``ValueError`` with an actionable message when one episode's
     working set cannot stay VMEM-resident; return the plan when it fits."""
     plan = episode_vmem_plan(
         steps=steps, capacity=capacity, state_dim=state_dim,
         action_dim=action_dim, hidden=hidden, num_updates=num_updates,
-        batch_size=batch_size, pad=pad, env_state_bytes=env_state_bytes)
-    budget = VMEM_BYTES if budget_bytes is None else int(budget_bytes)
+        batch_size=batch_size, pad=pad, device_kind=device_kind,
+        env_state_bytes=env_state_bytes)
+    budget = plan["budget_bytes"]
     if plan["pipelined_bytes"] <= budget:
         return plan
     top = sorted(plan["contributions"].items(), key=lambda kv: -kv[1])[:3]
@@ -103,23 +124,24 @@ def check_episode_vmem_fit(*, chunk, steps, capacity, state_dim, action_dim,
         f"steps={steps}, capacity={capacity}, space k={state_dim}/"
         f"m={action_dim} needs "
         f"{plan['pipelined_bytes'] / 2 ** 20:.2f} MiB per grid instance "
-        f"(x{_PIPELINE_FACTOR} pipelining) against a "
-        f"{budget / 2 ** 20:.2f} MiB budget. Top contributors: {detail}. "
+        f"(x{_PIPELINE_FACTOR} pipelining) against the "
+        f"{budget / 2 ** 20:.2f} MiB budget of a {device_kind}. Top "
+        f"contributors: {detail}. "
         f"Use the standard scan engine (REPRO_MEGAKERNEL=off) for this "
         f"configuration, or shrink the named knobs.")
 
 
 def suggest_max_capacity(*, steps, state_dim, action_dim, hidden,
-                         num_updates, batch_size, pad,
-                         env_state_bytes=0, budget_bytes=None):
+                         num_updates, batch_size, pad, device_kind,
+                         env_state_bytes=0):
     """Largest replay capacity that still fits — the error message's main
     remedy, computed rather than guessed."""
-    budget = VMEM_BYTES if budget_bytes is None else int(budget_bytes)
     base = episode_vmem_plan(
         steps=steps, capacity=0, state_dim=state_dim,
         action_dim=action_dim, hidden=hidden, num_updates=num_updates,
-        batch_size=batch_size, pad=pad, env_state_bytes=env_state_bytes)
+        batch_size=batch_size, pad=pad, device_kind=device_kind,
+        env_state_bytes=env_state_bytes)
     fixed = base["per_session_bytes"]
     per_row = (2 * int(state_dim) + int(action_dim) + 1) * 4
-    headroom = budget // _PIPELINE_FACTOR - fixed
+    headroom = base["budget_bytes"] // _PIPELINE_FACTOR - fixed
     return max(0, math.floor(headroom / per_row))

@@ -44,8 +44,8 @@ from repro.kernels.episode_fused import (EpisodeKernelSpec, EpisodeOperands,
                                          episode_fused_xla)
 from repro.kernels.ops import episode_kernel_mode
 from repro.kernels.ref import episode_fused_ref
-from repro.roofline import (check_episode_vmem_fit, episode_vmem_plan,
-                            suggest_max_capacity)
+from repro.roofline import (TARGET_DEVICE_KIND, check_episode_vmem_fit,
+                            episode_vmem_plan, suggest_max_capacity)
 
 from tests.test_ddpg_fused import _assert_learner_close, _max_ulp
 from tests.test_episode import _assert_bitwise_equal_runs, _tuner
@@ -289,7 +289,8 @@ def test_megakernel_composition_refusals(monkeypatch):
 # ---------------------------------------------------------------------------
 
 _FIT_KW = dict(steps=5, state_dim=8, action_dim=8, hidden=(64, 64),
-               num_updates=96, batch_size=16, pad=128)
+               num_updates=96, batch_size=16, pad=128,
+               device_kind=TARGET_DEVICE_KIND)
 
 
 def test_vmem_fit_rejects_oversized_capacity():

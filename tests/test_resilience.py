@@ -416,12 +416,12 @@ def test_dead_chunk_quarantines_sessions_and_survivors_stay_bitwise(
         tmp_path):
     ref, _ = _service(tmp_path / "ref")
     chaos = ChaosConfig(fail_chunks=((1, 99),))  # chunk 1 never stages
-    # on_failure="raise" is forced to "skip" inside advance: a persistent
-    # service quarantines, it never crashes
+    # on_failure="skip": the operator asked the service to quarantine a
+    # dead chunk's sessions rather than raise
     svc, sids = _service(
         tmp_path / "chaotic",
         supervisor=ChunkSupervisor(max_retries=1, backoff_seconds=0.0,
-                                   on_failure="raise"),
+                                   on_failure="skip"),
         chaos=chaos.host())
     ref.advance(4)
     svc.advance(4)

@@ -138,10 +138,7 @@ def test_compressed_pmean_in_shard_map():
     body = """
     import jax, numpy as np, jax.numpy as jnp, functools
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map          # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_test_mesh
     from repro.training.compression import compress_and_pmean
 
