@@ -447,7 +447,6 @@ def bench_service(n: int, chunk: int, steps: int, updates: int = 96,
         "sessions": n, "chunk": chunk, "steps": steps, "updates": updates,
         "session_steps_per_sec": meas["median"], "min": meas["min"],
         "noise_band": meas["noise_band"],
-        "peak_device_bytes": stats["peak_device_bytes"],
         "executable_cache_size": stats["executable_cache_size"],
     }
 

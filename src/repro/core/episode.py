@@ -71,6 +71,7 @@ import numpy as np
 from repro.core.action_mapping import ParamSpace, jax_coord_maps
 from repro.core.ddpg import DDPGConfig, actor_apply, _learn_scan
 from repro.core.scalarization import metric_bounds, normalize_state
+from repro.core.spans import phase
 
 
 class BufferState(NamedTuple):
@@ -217,59 +218,67 @@ def _build_episode(step_fn, space: ParamSpace, cfg: DDPGConfig, actor_tx,
     def one_step(params, w_vec, lo, span, carry, x):
         use_warmup, warmup_a, noise = x
 
-        # act: LHS warmup override, else policy + pre-drawn OU noise. The
-        # barrier isolates the actor forward the same way the env step and
-        # learner are isolated (see envs.base.barriered_step): each phase of
-        # the Fig. 1 loop is its own fusion island, keeping per-phase CPU
-        # codegen aligned with the host loop's standalone dispatches.
-        actor, state_vec = fusion_barrier(
-            (carry.ddpg.actor, carry.state_vec))
-        obs = state_vec if mask is None else state_vec * mask
-        policy = fusion_barrier(actor_apply(actor, obs))
-        explored = jnp.clip(policy + noise, 0.0, 1.0)
-        action = jnp.where(use_warmup, jnp.clip(warmup_a, 0.0, 1.0), explored)
+        # the named scopes (act, env, reward, store, learn) name each phase
+        # of the Fig. 1 loop in the compiled program's op metadata
+        with jax.named_scope("act"):
+            # LHS warmup override, else policy + pre-drawn OU noise. The
+            # barrier isolates the actor forward the same way the env step
+            # and learner are isolated (see envs.base.barriered_step): each
+            # phase of the Fig. 1 loop is its own fusion island, keeping
+            # per-phase CPU codegen aligned with the host loop's standalone
+            # dispatches.
+            actor, state_vec = fusion_barrier(
+                (carry.ddpg.actor, carry.state_vec))
+            obs = state_vec if mask is None else state_vec * mask
+            policy = fusion_barrier(actor_apply(actor, obs))
+            explored = jnp.clip(policy + noise, 0.0, 1.0)
+            action = jnp.where(use_warmup, jnp.clip(warmup_a, 0.0, 1.0),
+                               explored)
 
-        # compact trace: the knob indices the env's own quantization lands
-        # on (f32 maps — identical to the env dynamics' decode by
-        # construction)
-        action_idx = jnp.stack(
-            [coord_maps[j](action[j])["idx"] for j in range(space.dim)]
-        ).astype(idx_dtype)
+            # compact trace: the knob indices the env's own quantization
+            # lands on (f32 maps — identical to the env dynamics' decode by
+            # construction)
+            action_idx = jnp.stack(
+                [coord_maps[j](action[j])["idx"] for j in range(space.dim)]
+            ).astype(idx_dtype)
 
-        # env transition (pure model) + state normalization; barriered_step
-        # keeps the env subgraph an isolated fusion island with the same
-        # scan-body structure the ModelEnv adapter compiles (see
-        # envs.base.barriered_step)
-        env_state, metrics_vec, restart = barriered_step(
-            step_fn, params, carry.env_state, action, False)
-        norm = jnp.where(span > 0,
-                         jnp.clip((metrics_vec - lo) / span, 0.0, 1.0), 0.0)
+        with jax.named_scope("env"):
+            # env transition (pure model) + state normalization;
+            # barriered_step keeps the env subgraph an isolated fusion
+            # island with the same scan-body structure the ModelEnv adapter
+            # compiles (see envs.base.barriered_step)
+            env_state, metrics_vec, restart = barriered_step(
+                step_fn, params, carry.env_state, action, False)
+            norm = jnp.where(
+                span > 0, jnp.clip((metrics_vec - lo) / span, 0.0, 1.0), 0.0)
 
-        # objective: serial float32 fold in state order (zero-weight terms are
-        # exact no-ops) — bit-aligned with Scalarizer.objective
-        obj = jnp.float32(0.0)
-        for j in range(norm.shape[0]):
-            obj = obj + w_vec[j] * norm[j]
-        reward = (obj - carry.objective) / jnp.maximum(
-            carry.objective, jnp.float32(1e-6))
+        with jax.named_scope("reward"):
+            # objective: serial float32 fold in state order (zero-weight
+            # terms are exact no-ops) — bit-aligned with Scalarizer.objective
+            obj = jnp.float32(0.0)
+            for j in range(norm.shape[0]):
+                obj = obj + w_vec[j] * norm[j]
+            reward = (obj - carry.objective) / jnp.maximum(
+                carry.objective, jnp.float32(1e-6))
 
         if learn:  # observe: FIFO write, exactly ReplayBuffer.add
-            buf = carry.buffer
-            capacity = buf.s.shape[0]
-            i = buf.next_slot
-            # the stored s/s2 rows are what the LEARNER observed: under a
-            # local-observation mask the invisible metrics are zeroed, so
-            # replayed minibatches match the masked actor inputs
-            s_row = (carry.state_vec if mask is None
-                     else carry.state_vec * mask)
-            s2_row = norm if mask is None else norm * mask
-            buf = BufferState(
-                s=buf.s.at[i].set(s_row.astype(buf.s.dtype)),
-                a=buf.a.at[i].set(action.astype(buf.a.dtype)),
-                r=buf.r.at[i].set(reward.astype(buf.r.dtype)),
-                s2=buf.s2.at[i].set(s2_row.astype(buf.s2.dtype)),
-                next_slot=(i + 1) % capacity,
-                size=jnp.minimum(buf.size + 1, capacity))
+            with jax.named_scope("store"):
+                buf = carry.buffer
+                capacity = buf.s.shape[0]
+                i = buf.next_slot
+                # the stored s/s2 rows are what the LEARNER observed: under
+                # a local-observation mask the invisible metrics are zeroed,
+                # so replayed minibatches match the masked actor inputs
+                s_row = (carry.state_vec if mask is None
+                         else carry.state_vec * mask)
+                s2_row = norm if mask is None else norm * mask
+                buf = BufferState(
+                    s=buf.s.at[i].set(s_row.astype(buf.s.dtype)),
+                    a=buf.a.at[i].set(action.astype(buf.a.dtype)),
+                    r=buf.r.at[i].set(reward.astype(buf.r.dtype)),
+                    s2=buf.s2.at[i].set(s2_row.astype(buf.s2.dtype)),
+                    next_slot=(i + 1) % capacity,
+                    size=jnp.minimum(buf.size + 1, capacity))
         else:
             buf = carry.buffer
         if do_updates:
@@ -277,14 +286,16 @@ def _build_episode(step_fn, space: ParamSpace, cfg: DDPGConfig, actor_tx,
             # this same step (learn=True), so minibatch sampling never sees
             # an empty buffer — the invariant sample_minibatch_indices
             # requires now that the silent zero-index clamp is gone.
-            learn_key, k = jax.random.split(carry.learn_key)
-            learn_in = fusion_barrier((carry.ddpg, buf, k))
-            ddpg, _ = fusion_barrier(_learn_scan(
-                learn_in[0],
-                (learn_in[1].s, learn_in[1].a, learn_in[1].r, learn_in[1].s2),
-                learn_in[1].size, learn_in[2],
-                cfg, actor_tx, critic_tx, num_updates,
-                kernel_mode=kernel_mode))
+            with jax.named_scope("learn"):
+                learn_key, k = jax.random.split(carry.learn_key)
+                learn_in = fusion_barrier((carry.ddpg, buf, k))
+                ddpg, _ = fusion_barrier(_learn_scan(
+                    learn_in[0],
+                    (learn_in[1].s, learn_in[1].a, learn_in[1].r,
+                     learn_in[1].s2),
+                    learn_in[1].size, learn_in[2],
+                    cfg, actor_tx, critic_tx, num_updates,
+                    kernel_mode=kernel_mode))
         else:
             learn_key, ddpg = carry.learn_key, carry.ddpg
 
@@ -357,29 +368,32 @@ def _build_cell_episode(step_fn, space: ParamSpace, cfg: DDPGConfig,
         if rz is not None:
             health, carry = carry.health, carry.base
 
-        # act (per session, vmapped over the cell)
-        actor, state_vec = fusion_barrier(
-            (carry.ddpg.actor, carry.state_vec))
-        obs = state_vec if mask is None else state_vec * mask
-        policy = fusion_barrier(jax.vmap(actor_apply)(actor, obs))
-        explored = jnp.clip(policy + noise, 0.0, 1.0)
-        action = jnp.where(use_warmup[:, None],
-                           jnp.clip(warmup_a, 0.0, 1.0), explored)
-        action_idx = jax.vmap(idx_of)(action)
+        with jax.named_scope("act"):
+            # per session, vmapped over the cell
+            actor, state_vec = fusion_barrier(
+                (carry.ddpg.actor, carry.state_vec))
+            obs = state_vec if mask is None else state_vec * mask
+            policy = fusion_barrier(jax.vmap(actor_apply)(actor, obs))
+            explored = jnp.clip(policy + noise, 0.0, 1.0)
+            action = jnp.where(use_warmup[:, None],
+                               jnp.clip(warmup_a, 0.0, 1.0), explored)
+            action_idx = jax.vmap(idx_of)(action)
 
-        # env transition + normalization (per session)
-        env_state, metrics_vec, restart = jax.vmap(
-            lambda p, es, a: barriered_step(step_fn, p, es, a, False)
-        )(params, carry.env_state, action)
-        norm = jnp.where(span > 0,
-                         jnp.clip((metrics_vec - lo) / span, 0.0, 1.0), 0.0)
+        with jax.named_scope("env"):
+            # env transition + normalization (per session)
+            env_state, metrics_vec, restart = jax.vmap(
+                lambda p, es, a: barriered_step(step_fn, p, es, a, False)
+            )(params, carry.env_state, action)
+            norm = jnp.where(
+                span > 0, jnp.clip((metrics_vec - lo) / span, 0.0, 1.0), 0.0)
 
-        # objective: same serial float32 fold per lane as the off path
-        obj = jnp.float32(0.0)
-        for j in range(norm.shape[1]):
-            obj = obj + w_vec[:, j] * norm[:, j]
-        reward = (obj - carry.objective) / jnp.maximum(
-            carry.objective, jnp.float32(1e-6))
+        with jax.named_scope("reward"):
+            # objective: same serial float32 fold per lane as the off path
+            obj = jnp.float32(0.0)
+            for j in range(norm.shape[1]):
+                obj = obj + w_vec[:, j] * norm[:, j]
+            reward = (obj - carry.objective) / jnp.maximum(
+                carry.objective, jnp.float32(1e-6))
 
         if rz is not None:
             # per-lane corrupted-observation flag: these lanes are recorded
@@ -391,93 +405,98 @@ def _build_cell_episode(step_fn, space: ParamSpace, cfg: DDPGConfig,
         else:
             contrib = active
 
-        s_row = (carry.state_vec if mask is None
-                 else carry.state_vec * mask)
-        s2_row = norm if mask is None else norm * mask
-        buf = carry.buffer
-        if learn and shared:
-            # merged cell FIFO: every ACTIVE member appends, in session
-            # order, to the one shared window (exactly
-            # BatchedReplayBuffer(groups=...).add); inactive (padding)
-            # lanes scatter out of bounds and are dropped
-            capacity = buf.s.shape[0]
-            n_act = contrib.astype(jnp.int32)
-            offs = jnp.cumsum(n_act) - 1
-            wrote = jnp.sum(n_act)
-            pos = jnp.where(contrib, (buf.next_slot + offs) % capacity,
-                            capacity)
-            buf = BufferState(
-                s=buf.s.at[pos].set(s_row.astype(buf.s.dtype), mode="drop"),
-                a=buf.a.at[pos].set(action.astype(buf.a.dtype),
-                                    mode="drop"),
-                r=buf.r.at[pos].set(reward.astype(buf.r.dtype),
-                                    mode="drop"),
-                s2=buf.s2.at[pos].set(s2_row.astype(buf.s2.dtype),
-                                      mode="drop"),
-                next_slot=(buf.next_slot + wrote) % capacity,
-                size=jnp.minimum(buf.size + wrote, capacity))
-        elif learn:
-            # independent per-session FIFOs (averaging-only mode), exactly
-            # the off path's write vmapped over the cell
-            capacity = buf.s.shape[1]
-            lane = jnp.arange(cs)
-            i = buf.next_slot
-            if rz is not None:
-                pos = jnp.where(contrib, i, capacity)  # OOB -> drop
+        with jax.named_scope("store"):
+            s_row = (carry.state_vec if mask is None
+                     else carry.state_vec * mask)
+            s2_row = norm if mask is None else norm * mask
+            buf = carry.buffer
+            if learn and shared:
+                # merged cell FIFO: every ACTIVE member appends, in session
+                # order, to the one shared window (exactly
+                # BatchedReplayBuffer(groups=...).add); inactive (padding)
+                # lanes scatter out of bounds and are dropped
+                capacity = buf.s.shape[0]
+                n_act = contrib.astype(jnp.int32)
+                offs = jnp.cumsum(n_act) - 1
+                wrote = jnp.sum(n_act)
+                pos = jnp.where(contrib, (buf.next_slot + offs) % capacity,
+                                capacity)
                 buf = BufferState(
-                    s=buf.s.at[lane, pos].set(s_row.astype(buf.s.dtype),
-                                              mode="drop"),
-                    a=buf.a.at[lane, pos].set(action.astype(buf.a.dtype),
-                                              mode="drop"),
-                    r=buf.r.at[lane, pos].set(reward.astype(buf.r.dtype),
-                                              mode="drop"),
-                    s2=buf.s2.at[lane, pos].set(s2_row.astype(buf.s2.dtype),
-                                                mode="drop"),
-                    next_slot=jnp.where(contrib, (i + 1) % capacity, i),
-                    size=jnp.where(contrib,
-                                   jnp.minimum(buf.size + 1, capacity),
-                                   buf.size))
-            else:
-                buf = BufferState(
-                    s=buf.s.at[lane, i].set(s_row.astype(buf.s.dtype)),
-                    a=buf.a.at[lane, i].set(action.astype(buf.a.dtype)),
-                    r=buf.r.at[lane, i].set(reward.astype(buf.r.dtype)),
-                    s2=buf.s2.at[lane, i].set(s2_row.astype(buf.s2.dtype)),
-                    next_slot=(i + 1) % capacity,
-                    size=jnp.minimum(buf.size + 1, capacity))
+                    s=buf.s.at[pos].set(s_row.astype(buf.s.dtype),
+                                        mode="drop"),
+                    a=buf.a.at[pos].set(action.astype(buf.a.dtype),
+                                        mode="drop"),
+                    r=buf.r.at[pos].set(reward.astype(buf.r.dtype),
+                                        mode="drop"),
+                    s2=buf.s2.at[pos].set(s2_row.astype(buf.s2.dtype),
+                                          mode="drop"),
+                    next_slot=(buf.next_slot + wrote) % capacity,
+                    size=jnp.minimum(buf.size + wrote, capacity))
+            elif learn:
+                # independent per-session FIFOs (averaging-only mode),
+                # exactly the off path's write vmapped over the cell
+                capacity = buf.s.shape[1]
+                lane = jnp.arange(cs)
+                i = buf.next_slot
+                if rz is not None:
+                    pos = jnp.where(contrib, i, capacity)  # OOB -> drop
+                    buf = BufferState(
+                        s=buf.s.at[lane, pos].set(
+                            s_row.astype(buf.s.dtype), mode="drop"),
+                        a=buf.a.at[lane, pos].set(
+                            action.astype(buf.a.dtype), mode="drop"),
+                        r=buf.r.at[lane, pos].set(
+                            reward.astype(buf.r.dtype), mode="drop"),
+                        s2=buf.s2.at[lane, pos].set(
+                            s2_row.astype(buf.s2.dtype), mode="drop"),
+                        next_slot=jnp.where(contrib, (i + 1) % capacity, i),
+                        size=jnp.where(contrib,
+                                       jnp.minimum(buf.size + 1, capacity),
+                                       buf.size))
+                else:
+                    buf = BufferState(
+                        s=buf.s.at[lane, i].set(s_row.astype(buf.s.dtype)),
+                        a=buf.a.at[lane, i].set(action.astype(buf.a.dtype)),
+                        r=buf.r.at[lane, i].set(reward.astype(buf.r.dtype)),
+                        s2=buf.s2.at[lane, i].set(
+                            s2_row.astype(buf.s2.dtype)),
+                        next_slot=(i + 1) % capacity,
+                        size=jnp.minimum(buf.size + 1, capacity))
 
         lmetrics = None
         if do_updates:
-            ks = jax.vmap(jax.random.split)(carry.learn_key)
-            learn_key, k = ks[:, 0], ks[:, 1]
-            learn_in = fusion_barrier((carry.ddpg, buf, k))
-            dbuf = learn_in[1]
-            # dropped writes mean the window CAN be empty under resilience
-            # (every lane corrupted at step 0); clamp the sampled size and
-            # discard the no-data update below
-            size_of = ((lambda sz: jnp.maximum(sz, 1)) if rz is not None
-                       else (lambda sz: sz))
-            if shared:
-                # every member learner samples its own minibatches from the
-                # MERGED window: data broadcast, state/key batched
-                data = (dbuf.s, dbuf.a, dbuf.r, dbuf.s2)
-                ddpg, lmetrics = fusion_barrier(jax.vmap(
-                    lambda st, kk: _learn_scan(
-                        st, data, size_of(dbuf.size), kk, cfg, actor_tx,
-                        critic_tx, num_updates, kernel_mode=kernel_mode)
-                )(learn_in[0], learn_in[2]))
-                empty = dbuf.size == 0
-            else:
-                ddpg, lmetrics = fusion_barrier(jax.vmap(
-                    lambda st, d, sz, kk: _learn_scan(
-                        st, d, size_of(sz), kk, cfg, actor_tx, critic_tx,
-                        num_updates, kernel_mode=kernel_mode)
-                )(learn_in[0], (dbuf.s, dbuf.a, dbuf.r, dbuf.s2),
-                  dbuf.size, learn_in[2]))
-                empty = dbuf.size == 0
-            if rz is not None:
-                ddpg = select_tree(jnp.broadcast_to(empty, (cs,)),
-                                   carry.ddpg, ddpg)
+            with jax.named_scope("learn"):
+                ks = jax.vmap(jax.random.split)(carry.learn_key)
+                learn_key, k = ks[:, 0], ks[:, 1]
+                learn_in = fusion_barrier((carry.ddpg, buf, k))
+                dbuf = learn_in[1]
+                # dropped writes mean the window CAN be empty under
+                # resilience (every lane corrupted at step 0); clamp the
+                # sampled size and discard the no-data update below
+                size_of = ((lambda sz: jnp.maximum(sz, 1))
+                           if rz is not None else (lambda sz: sz))
+                if shared:
+                    # every member learner samples its own minibatches from
+                    # the MERGED window: data broadcast, state/key batched
+                    data = (dbuf.s, dbuf.a, dbuf.r, dbuf.s2)
+                    ddpg, lmetrics = fusion_barrier(jax.vmap(
+                        lambda st, kk: _learn_scan(
+                            st, data, size_of(dbuf.size), kk, cfg,
+                            actor_tx, critic_tx, num_updates,
+                            kernel_mode=kernel_mode)
+                    )(learn_in[0], learn_in[2]))
+                    empty = dbuf.size == 0
+                else:
+                    ddpg, lmetrics = fusion_barrier(jax.vmap(
+                        lambda st, d, sz, kk: _learn_scan(
+                            st, d, size_of(sz), kk, cfg, actor_tx,
+                            critic_tx, num_updates, kernel_mode=kernel_mode)
+                    )(learn_in[0], (dbuf.s, dbuf.a, dbuf.r, dbuf.s2),
+                      dbuf.size, learn_in[2]))
+                    empty = dbuf.size == 0
+                if rz is not None:
+                    ddpg = select_tree(jnp.broadcast_to(empty, (cs,)),
+                                       carry.ddpg, ddpg)
         else:
             learn_key, ddpg = carry.learn_key, carry.ddpg
 
@@ -1143,8 +1162,16 @@ def stream_chunks(call, stage, drain, num_chunks: int,
     ``async`` (whether the background stream ran), ``stage_seconds`` (time
     the worker spent building + staging operands), ``stage_wait_seconds``
     (time the main thread blocked waiting for a staged chunk),
-    ``drain_seconds`` and ``overlap_efficiency`` (fraction of staging time
-    hidden under compute: ``1 - wait / stage``).
+    ``drain_seconds`` (the whole drain: the wait for the chunk's results
+    plus ``drain``'s copy and decode), ``drain_block_seconds`` (the wait
+    alone: ``jax.block_until_ready`` on the chunk's results) and
+    ``overlap_efficiency`` (fraction of staging time hidden under compute:
+    ``1 - wait / stage``).
+
+    The unsupervised schedules name their phases on the profiler's clock
+    (``core.spans``): ``fleet.stage`` (on the thread that stages),
+    ``fleet.stage_wait``, ``fleet.dispatch``, ``fleet.drain.wait`` and
+    ``fleet.drain.copy``, each with its ``chunk``.
 
     ``supervisor`` (a ``core.resilience.ChunkSupervisor``) runs the stream
     under host supervision: strictly serial (chunking/overlap are pure
@@ -1168,7 +1195,7 @@ def stream_chunks(call, stage, drain, num_chunks: int,
     st = staging if staging is not None else {}
     st.update(**{"async": False, "stage_seconds": 0.0,
                  "stage_wait_seconds": 0.0, "drain_seconds": 0.0,
-                 "overlap_efficiency": 0.0})
+                 "drain_block_seconds": 0.0, "overlap_efficiency": 0.0})
     if num_chunks <= 0:
         return None if supervisor is None else _empty_stream_stats()
     if supervisor is not None:
@@ -1176,14 +1203,24 @@ def stream_chunks(call, stage, drain, num_chunks: int,
                                   supervisor, chaos)
 
     def timed_stage(ci):
-        t0 = time.perf_counter()
-        args = stage(ci)
-        return args, time.perf_counter() - t0
+        with phase("stage", chunk=ci) as p:
+            args = stage(ci)
+        return args, p.seconds
+
+    def dispatch(ci, staged):
+        with phase("dispatch", chunk=ci):
+            return call(staged)
 
     def timed_drain(ci, out):
-        t0 = time.perf_counter()
-        drain(ci, out)
-        st["drain_seconds"] += time.perf_counter() - t0
+        # the device->host copies are already in flight
+        # (``_start_host_copy``); waiting first splits the drain's time into
+        # the device's and the host's share without changing a value
+        with phase("drain.wait", chunk=ci) as wait:
+            jax.block_until_ready(out)
+        with phase("drain.copy", chunk=ci) as copy:
+            drain(ci, out)
+        st["drain_block_seconds"] += wait.seconds
+        st["drain_seconds"] += wait.seconds + copy.seconds
 
     if overlap:
         st["async"] = True
@@ -1191,11 +1228,11 @@ def stream_chunks(call, stage, drain, num_chunks: int,
         inflight = None
         fut = ex.submit(timed_stage, 0)
         for ci in range(num_chunks):
-            t0 = time.perf_counter()
-            staged, sdt = fut.result()  # block until chunk ci is on device
-            st["stage_wait_seconds"] += time.perf_counter() - t0
+            with phase("stage_wait", chunk=ci) as wait:
+                staged, sdt = fut.result()  # until chunk ci is on device
+            st["stage_wait_seconds"] += wait.seconds
             st["stage_seconds"] += sdt
-            out = call(staged)
+            out = dispatch(ci, staged)
             staged = None  # drop our handle; donation invalidated the carry
             _start_host_copy(out)  # D2H drains the moment compute finishes
             if ci + 1 < num_chunks:
@@ -1211,7 +1248,7 @@ def stream_chunks(call, stage, drain, num_chunks: int,
         staged, sdt = timed_stage(0)
         st["stage_seconds"] += sdt
         for ci in range(num_chunks):
-            out = call(staged)
+            out = dispatch(ci, staged)
             staged = None
             timed_drain(ci, out)
             if ci + 1 < num_chunks:

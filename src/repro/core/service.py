@@ -52,7 +52,6 @@ from repro.core.episode import (
     _compiled_episode,
     _pad_rows,
     decode_restarts,
-    live_device_bytes,
     stream_chunks,
 )
 from repro.core.fleet import replay_compact_trace
@@ -61,6 +60,7 @@ from repro.core.scalarization import (
     metric_bounds,
     normalize_state,
 )
+from repro.core.spans import phase
 from repro.core.tuner import (
     StepRecord,
     TuningResult,
@@ -72,6 +72,12 @@ from repro.checkpoint.store import (
     restore_into,
     save_checkpoint,
 )
+
+#: host phases whose seconds ``FleetService.counters`` keeps, as
+#: ``<phase>_seconds``; each is the span ``fleet.<phase>`` with ``_`` for
+#: ``.`` (``core.spans``)
+PHASES = ("join", "join_env", "join_init", "join_evaluate", "advance",
+          "boundary", "finalize", "prepare", "stream", "write_back")
 
 
 @dataclasses.dataclass
@@ -214,6 +220,9 @@ class FleetService:
         self._actor_tx = None
         self._critic_tx = None
         self.last_stats: dict = {}
+        # the operator's scrape point: monotone over the service's life
+        self.counters: dict = {**{f"{p}_seconds": 0.0 for p in PHASES},
+                               "joins": 0, "finalizes": 0, "rounds": 0}
 
     # -- membership requests ------------------------------------------------
 
@@ -260,58 +269,69 @@ class FleetService:
 
     def _new_session(self, sid, workload, weights, seed, label,
                      evaluate_default: bool = True) -> _Session:
-        env = self.env_factory(workload, seed)
-        if self.cfg is None:
-            self.cfg = DDPGConfig.for_env(env)
-        scal = Scalarizer(weights=weights, specs=env.metric_specs)
-        # identical to FleetAgent's per-seed streams (width-1 vmap init
-        # produces the same per-key values as any other width)
-        states, (atx, ctx) = fleet_init(
-            jnp.stack([jax.random.PRNGKey(seed)]), self.cfg)
-        if self._actor_tx is None:
-            self._actor_tx, self._critic_tx = atx, ctx
-        ddpg = jax.tree_util.tree_map(lambda x: np.asarray(x)[0], states)
-        cap, k, m = self.buffer_capacity, self.cfg.state_dim, \
-            self.cfg.action_dim
-        buf = {"s": np.zeros((cap, k), np.float32),
-               "a": np.zeros((cap, m), np.float32),
-               "r": np.zeros((cap,), np.float32),
-               "s2": np.zeros((cap, k), np.float32),
-               "next": 0, "size": 0}
-        default_config = env.param_space.default_config()
-        if evaluate_default:
-            default_metrics = evaluate_config(env, default_config,
-                                              self.eval_runs)
-        else:
-            default_metrics = {}  # restore path fills from the checkpoint
-        guard = None
-        if self.policy is not None:
-            from repro.core.guardrails import init_guard_state
-            guard = init_guard_state(
-                env.param_space, default_config,
-                scal.objective(default_metrics) if default_metrics else 0.0)
-        health = None
-        if self.resilience is not None:
-            from repro.core.resilience import init_health_state
-            health = init_health_state(ddpg, self.resilience)
-        return _Session(
-            sid=sid, label=label, workload=workload, weights=weights,
-            seed=seed, env=env, scalarizer=scal, ddpg=ddpg, buf=buf,
-            learn_key=np.asarray(jax.random.PRNGKey(seed + 3)),
-            noise=OUNoise(m, seed=seed + 1),
-            warmup_plan=lhs_warmup_plan(
-                np.random.default_rng(seed + 2), self.warmup_steps, m),
-            steps_taken=0,
-            default_config=dict(default_config),
-            default_metrics=dict(default_metrics),
-            cur_config=dict(default_config),
-            cur_metrics=dict(default_metrics),
-            best_config=dict(default_config),
-            best_metrics=dict(default_metrics),
-            best_objective=(scal.objective(default_metrics)
-                            if default_metrics else float("-inf")),
-            history=[], restart_seconds=0.0, joined_at=time.perf_counter(),
-            guard=guard, health=health)
+        """Build one session (``request_join``, or ``restore`` rebuilding a
+        checkpointed one): its env, its learner and host buffers, and the
+        evaluation of the default configuration."""
+        with phase("join", self.counters, sid=sid):
+            self.counters["joins"] += 1
+            with phase("join.env", self.counters):
+                env = self.env_factory(workload, seed)
+            with phase("join.init", self.counters):
+                if self.cfg is None:
+                    self.cfg = DDPGConfig.for_env(env)
+                scal = Scalarizer(weights=weights, specs=env.metric_specs)
+                # identical to FleetAgent's per-seed streams (width-1 vmap init
+                # produces the same per-key values as any other width)
+                states, (atx, ctx) = fleet_init(
+                    jnp.stack([jax.random.PRNGKey(seed)]), self.cfg)
+                if self._actor_tx is None:
+                    self._actor_tx, self._critic_tx = atx, ctx
+                ddpg = jax.tree_util.tree_map(lambda x: np.asarray(x)[0],
+                                              states)
+                cap, k, m = self.buffer_capacity, self.cfg.state_dim, \
+                    self.cfg.action_dim
+                buf = {"s": np.zeros((cap, k), np.float32),
+                       "a": np.zeros((cap, m), np.float32),
+                       "r": np.zeros((cap,), np.float32),
+                       "s2": np.zeros((cap, k), np.float32),
+                       "next": 0, "size": 0}
+                learn_key = np.asarray(jax.random.PRNGKey(seed + 3))
+                noise = OUNoise(m, seed=seed + 1)
+                warmup_plan = lhs_warmup_plan(
+                    np.random.default_rng(seed + 2), self.warmup_steps, m)
+                default_config = env.param_space.default_config()
+            if evaluate_default:
+                with phase("join.evaluate", self.counters):
+                    default_metrics = evaluate_config(env, default_config,
+                                                      self.eval_runs)
+            else:
+                default_metrics = {}  # restore path fills from the checkpoint
+            guard = None
+            if self.policy is not None:
+                from repro.core.guardrails import init_guard_state
+                guard = init_guard_state(
+                    env.param_space, default_config,
+                    scal.objective(default_metrics) if default_metrics
+                    else 0.0)
+            health = None
+            if self.resilience is not None:
+                from repro.core.resilience import init_health_state
+                health = init_health_state(ddpg, self.resilience)
+            return _Session(
+                sid=sid, label=label, workload=workload, weights=weights,
+                seed=seed, env=env, scalarizer=scal, ddpg=ddpg, buf=buf,
+                learn_key=learn_key, noise=noise, warmup_plan=warmup_plan,
+                steps_taken=0,
+                default_config=dict(default_config),
+                default_metrics=dict(default_metrics),
+                cur_config=dict(default_config),
+                cur_metrics=dict(default_metrics),
+                best_config=dict(default_config),
+                best_metrics=dict(default_metrics),
+                best_objective=(scal.objective(default_metrics)
+                                if default_metrics else float("-inf")),
+                history=[], restart_seconds=0.0, joined_at=time.perf_counter(),
+                guard=guard, health=health)
 
     # -- boundary: apply the request queue -----------------------------------
 
@@ -421,47 +441,60 @@ class FleetService:
 
     def _finalize(self, sess: _Session) -> None:
         """§III-E final recommendation for one departing session."""
-        state_vec = normalize_state(sess.cur_metrics, sess.env.metric_specs,
-                                    sess.env.state_metrics)
-        a = np.asarray(actor_apply(
-            jax.tree_util.tree_map(jnp.asarray, sess.ddpg.actor),
-            jnp.asarray(state_vec, jnp.float32)))
-        policy_config = sess.env.param_space.to_config(
-            np.clip(a, 0.0, 1.0).astype(np.float32))
-        config, best_metrics, replaced = recommend_final(
-            sess.scalarizer, sess.best_config, policy_config,
-            lambda c: evaluate_config(sess.env, c, self.eval_runs))
-        if replaced:
-            sess.best_config = dict(config)
-        self._completed[sess.sid] = TuningResult(
-            best_config=dict(sess.best_config),
-            best_objective=sess.scalarizer.objective(best_metrics),
-            best_metrics=best_metrics,
-            default_config=dict(sess.default_config),
-            default_metrics=dict(sess.default_metrics),
-            history=list(sess.history),
-            simulated_restart_seconds=float(sess.restart_seconds),
-            wall_seconds=time.perf_counter() - sess.joined_at,
-            guardrail_stats=self._session_guardrail_stats(sess),
-            health_stats=self._session_health_stats(sess))
+        with phase("finalize", self.counters, sid=sess.sid):
+            self.counters["finalizes"] += 1
+            state_vec = normalize_state(sess.cur_metrics,
+                                        sess.env.metric_specs,
+                                        sess.env.state_metrics)
+            a = np.asarray(actor_apply(
+                jax.tree_util.tree_map(jnp.asarray, sess.ddpg.actor),
+                jnp.asarray(state_vec, jnp.float32)))
+            policy_config = sess.env.param_space.to_config(
+                np.clip(a, 0.0, 1.0).astype(np.float32))
+            config, best_metrics, replaced = recommend_final(
+                sess.scalarizer, sess.best_config, policy_config,
+                lambda c: evaluate_config(sess.env, c, self.eval_runs))
+            if replaced:
+                sess.best_config = dict(config)
+            self._completed[sess.sid] = TuningResult(
+                best_config=dict(sess.best_config),
+                best_objective=sess.scalarizer.objective(best_metrics),
+                best_metrics=best_metrics,
+                default_config=dict(sess.default_config),
+                default_metrics=dict(sess.default_metrics),
+                history=list(sess.history),
+                simulated_restart_seconds=float(sess.restart_seconds),
+                wall_seconds=time.perf_counter() - sess.joined_at,
+                guardrail_stats=self._session_guardrail_stats(sess),
+                health_stats=self._session_health_stats(sess))
 
     # -- the serving loop ----------------------------------------------------
 
     def advance(self, steps: int) -> list:
         """One boundary + ``steps`` fused tuning iterations for every active
-        session. Returns the sids that advanced (slot order)."""
-        self._apply_requests()
-        order = [sid for sid in self._slots if sid is not None]
-        if not order or steps <= 0:
-            return []
-        sessions = [self._sessions[sid] for sid in order]
-        quarantined = self._advance_sessions(sessions, steps)
-        self.total_steps += steps
-        for sid in quarantined:
-            # the chunk exhausted its supervised retries: its sessions keep
-            # their pre-episode state and leave through the normal path at
-            # the next boundary — bit-neutral for every surviving session
-            self.request_leave(sid)
+        session. Returns the sids that advanced (slot order).
+
+        A round that steps sessions replaces ``last_stats``; its
+        ``"phases"`` is the round's delta of ``counters``."""
+        before = dict(self.counters)
+        self.counters["rounds"] += 1
+        with phase("advance", self.counters, round=self.counters["rounds"]):
+            with phase("boundary", self.counters):
+                self._apply_requests()
+            order = [sid for sid in self._slots if sid is not None]
+            if not order or steps <= 0:
+                return []
+            sessions = [self._sessions[sid] for sid in order]
+            quarantined = self._advance_sessions(sessions, steps)
+            self.total_steps += steps
+            for sid in quarantined:
+                # the chunk exhausted its supervised retries: its sessions
+                # keep their pre-episode state and leave through the normal
+                # path at the next boundary — bit-neutral for every
+                # surviving session
+                self.request_leave(sid)
+        self.last_stats["phases"] = {
+            k: v - before[k] for k, v in self.counters.items()}
         return order
 
     def _resolve_obs_mask(self, env):
@@ -493,151 +526,156 @@ class FleetService:
         untouched (the drain never ran) and its sessions are handed back to
         ``advance`` for the leave path. The chunk schedule is pure
         scheduling, so skipping chunk i never perturbs chunk j."""
-        step_fns = {s.env.model.step_fn for s in sessions}
-        if len(step_fns) != 1:
-            raise ValueError("all service sessions must share one env model "
-                             "structure (same space / model class)")
-        cell_modes = self._cell_modes
-        cs = self.cell_size
-        shared_replay = cell_modes and self.sharing.shared_replay
-        obs_mask = self._resolve_obs_mask(sessions[0].env)
-        uindex = {s.sid: j for j, s in enumerate(sessions)}
+        with phase("prepare", self.counters):
+            step_fns = {s.env.model.step_fn for s in sessions}
+            if len(step_fns) != 1:
+                raise ValueError("all service sessions must share one env "
+                                 "model structure (same space / model "
+                                 "class)")
+            cell_modes = self._cell_modes
+            cs = self.cell_size
+            shared_replay = cell_modes and self.sharing.shared_replay
+            obs_mask = self._resolve_obs_mask(sessions[0].env)
+            uindex = {s.sid: j for j, s in enumerate(sessions)}
 
-        # -- per-session exploration, consumed ONCE per unique session -------
-        # (each session consumes ITS OWN streams at ITS OWN age — mixed-age
-        # chunks and, under sharing, mixed-age cells stay exact)
-        u = len(sessions)
-        cfg = self.cfg
-        k_dim, m_dim = cfg.state_dim, cfg.action_dim
-        use_warmup_u = np.zeros((u, steps), bool)
-        warmup_u = np.zeros((u, steps, m_dim), np.float32)
-        noise_u = np.zeros((u, steps, m_dim), np.float32)
-        for j, s in enumerate(sessions):
-            s0 = s.steps_taken
-            for t in range(steps):
-                if s0 + t < self.warmup_steps:
-                    use_warmup_u[j, t] = True
-                    warmup_u[j, t] = s.warmup_plan[s0 + t]
-                else:
-                    noise_u[j, t] = s.noise()
-            s.steps_taken += steps
+            # -- per-session exploration, consumed ONCE per unique session --
+            # (each session consumes ITS OWN streams at ITS OWN age —
+            # mixed-age chunks and, under sharing, mixed-age cells stay
+            # exact)
+            u = len(sessions)
+            cfg = self.cfg
+            k_dim, m_dim = cfg.state_dim, cfg.action_dim
+            use_warmup_u = np.zeros((u, steps), bool)
+            warmup_u = np.zeros((u, steps, m_dim), np.float32)
+            noise_u = np.zeros((u, steps, m_dim), np.float32)
+            for j, s in enumerate(sessions):
+                s0 = s.steps_taken
+                for t in range(steps):
+                    if s0 + t < self.warmup_steps:
+                        use_warmup_u[j, t] = True
+                        warmup_u[j, t] = s.warmup_plan[s0 + t]
+                    else:
+                        noise_u[j, t] = s.noise()
+                s.steps_taken += steps
 
-        if cell_modes:
-            # cell-ordered rows; vacant seats replicate the first live
-            # member (inactive, non-primary: results + state discarded)
-            rows, ridx, active_rows, primary_rows, row_cells = \
-                [], [], [], [], []
-            for cid in sorted(self._cells):
-                rec = self._cells[cid]
-                live = [sid for sid in rec["seats"] if sid is not None]
-                rep = self._sessions[live[0]]
-                for sid in rec["seats"]:
-                    s = self._sessions[sid] if sid is not None else rep
-                    rows.append(s)
-                    ridx.append(uindex[s.sid])
-                    active_rows.append(sid is not None)
-                    primary_rows.append(sid is not None)
-                    row_cells.append(cid)
-            ridx = np.asarray(ridx, np.int64)
-            active_rows = np.asarray(active_rows, bool)
-            primary_rows = np.asarray(primary_rows, bool)
-        else:
-            rows = list(sessions)
-            ridx = np.arange(u)
-            active_rows = np.ones((u,), bool)
-            primary_rows = np.ones((u,), bool)
-            row_cells = []
-        n = len(rows)
-        c = self.chunk  # fixed lease width: ONE compiled width, always
-        num_chunks = -(-n // c)
-        space = rows[0].env.param_space
-        env0 = rows[0].env
-        use_warmup = use_warmup_u[ridx]
-        warmup = warmup_u[ridx]
-        noise = noise_u[ridx]
+            if cell_modes:
+                # cell-ordered rows; vacant seats replicate the first live
+                # member (inactive, non-primary: results + state discarded)
+                rows, ridx, active_rows, primary_rows, row_cells = \
+                    [], [], [], [], []
+                for cid in sorted(self._cells):
+                    rec = self._cells[cid]
+                    live = [sid for sid in rec["seats"] if sid is not None]
+                    rep = self._sessions[live[0]]
+                    for sid in rec["seats"]:
+                        s = self._sessions[sid] if sid is not None else rep
+                        rows.append(s)
+                        ridx.append(uindex[s.sid])
+                        active_rows.append(sid is not None)
+                        primary_rows.append(sid is not None)
+                        row_cells.append(cid)
+                ridx = np.asarray(ridx, np.int64)
+                active_rows = np.asarray(active_rows, bool)
+                primary_rows = np.asarray(primary_rows, bool)
+            else:
+                rows = list(sessions)
+                ridx = np.arange(u)
+                active_rows = np.ones((u,), bool)
+                primary_rows = np.ones((u,), bool)
+                row_cells = []
+            n = len(rows)
+            c = self.chunk  # fixed lease width: ONE compiled width, always
+            num_chunks = -(-n // c)
+            space = rows[0].env.param_space
+            env0 = rows[0].env
+            use_warmup = use_warmup_u[ridx]
+            warmup = warmup_u[ridx]
+            noise = noise_u[ridx]
 
-        def stack_np(trees):
-            return jax.tree_util.tree_map(
-                lambda *xs: np.stack([np.asarray(x) for x in xs]), *trees)
+            def stack_np(trees):
+                return jax.tree_util.tree_map(
+                    lambda *xs: np.stack([np.asarray(x) for x in xs]), *trees)
 
-        params = stack_np([s.env.model.params for s in rows])
-        env_states = stack_np([s.env.model_state for s in rows])
-        ddpg_states = stack_np([s.ddpg for s in rows])
-        lo, span = metric_bounds(env0.metric_specs, env0.state_metrics)
-        k = lo.shape[0]
-        lo = np.broadcast_to(lo, (n, k))
-        span = np.broadcast_to(span, (n, k))
-        w_vec = np.stack([s.scalarizer.weight_vector(s.env.state_metrics)
-                          for s in rows])
-        state_vecs = np.stack([
-            normalize_state(s.cur_metrics, s.env.metric_specs,
-                            s.env.state_metrics) for s in rows])
-        objectives = np.array(
-            [np.float32(s.scalarizer.objective(s.cur_metrics))
-             for s in rows], np.float32)
-        if shared_replay:
-            # cell-granular merged windows: [G, cap, ...] + [G] cursors
-            cell_ids = sorted(self._cells)
-            cbufs = [self._cells[cid]["buf"] for cid in cell_ids]
-            buf_np = tuple(
-                np.stack([cb[key] for cb in cbufs])
-                for key in ("s", "a", "r", "s2"))
-            next_slots = np.array([cb["next"] for cb in cbufs], np.int32)
-            sizes = np.array([cb["size"] for cb in cbufs], np.int32)
-        else:
-            buf_np = tuple(
-                np.stack([s.buf[key] for s in rows])
-                for key in ("s", "a", "r", "s2"))
-            next_slots = np.array([s.buf["next"] for s in rows], np.int32)
-            sizes = np.array([s.buf["size"] for s in rows], np.int32)
-        learn_keys = np.stack([s.learn_key for s in rows])
+            params = stack_np([s.env.model.params for s in rows])
+            env_states = stack_np([s.env.model_state for s in rows])
+            ddpg_states = stack_np([s.ddpg for s in rows])
+            lo, span = metric_bounds(env0.metric_specs, env0.state_metrics)
+            k = lo.shape[0]
+            lo = np.broadcast_to(lo, (n, k))
+            span = np.broadcast_to(span, (n, k))
+            w_vec = np.stack([s.scalarizer.weight_vector(s.env.state_metrics)
+                              for s in rows])
+            state_vecs = np.stack([
+                normalize_state(s.cur_metrics, s.env.metric_specs,
+                                s.env.state_metrics) for s in rows])
+            objectives = np.array(
+                [np.float32(s.scalarizer.objective(s.cur_metrics))
+                 for s in rows], np.float32)
+            if shared_replay:
+                # cell-granular merged windows: [G, cap, ...] + [G] cursors
+                cell_ids = sorted(self._cells)
+                cbufs = [self._cells[cid]["buf"] for cid in cell_ids]
+                buf_np = tuple(
+                    np.stack([cb[key] for cb in cbufs])
+                    for key in ("s", "a", "r", "s2"))
+                next_slots = np.array([cb["next"] for cb in cbufs], np.int32)
+                sizes = np.array([cb["size"] for cb in cbufs], np.int32)
+            else:
+                buf_np = tuple(
+                    np.stack([s.buf[key] for s in rows])
+                    for key in ("s", "a", "r", "s2"))
+                next_slots = np.array([s.buf["next"] for s in rows], np.int32)
+                sizes = np.array([s.buf["size"] for s in rows], np.int32)
+            learn_keys = np.stack([s.learn_key for s in rows])
 
-        if cell_modes:
-            # the averaging cadence fires on each CELL's own step clock (a
-            # cell-level event: every seat agrees, whatever its member ages)
-            avg_now = np.zeros((n, steps), bool)
-            if self.sharing.averaging:
-                for j, cid in enumerate(row_cells):
-                    cst = self._cells[cid]["steps"]
-                    for t in range(steps):
-                        avg_now[j, t] = \
-                            ((cst + t + 1) % self.sharing.avg_every) == 0
-            active = np.broadcast_to(active_rows[:, None],
-                                     (n, steps)).copy()
+            if cell_modes:
+                # the averaging cadence fires on each CELL's own step clock
+                # (a cell-level event: every seat agrees, whatever its
+                # member ages)
+                avg_now = np.zeros((n, steps), bool)
+                if self.sharing.averaging:
+                    for j, cid in enumerate(row_cells):
+                        cst = self._cells[cid]["steps"]
+                        for t in range(steps):
+                            avg_now[j, t] = \
+                                ((cst + t + 1) % self.sharing.avg_every) == 0
+                active = np.broadcast_to(active_rows[:, None],
+                                         (n, steps)).copy()
 
-        base_fields = dict(
-            action_idx=np.zeros((n, steps, space.dim), space.index_dtype()),
-            metrics=np.zeros((n, steps, k), np.float32),
-            rewards=np.zeros((n, steps), np.float32),
-            objectives=np.zeros((n, steps), np.float32),
-            restarts=np.zeros((n, steps), np.float32))
-        guarded = self.policy is not None
-        resilient = self.resilience is not None
-        if guarded:
-            from repro.core.guardrails import (
-                GuardedCarry, GuardedEpisodeTrace)
-            guard = stack_np([s.guard for s in rows])
-            out = GuardedEpisodeTrace(
-                **base_fields,
-                guard_events=np.zeros((n, steps), np.uint8),
-                shadow_objectives=np.zeros((n, steps), np.float32))
-        elif resilient:
-            from repro.core.resilience import (
-                ResilientCarry, ResilientEpisodeTrace)
-            health = stack_np([s.health for s in rows])
-            out = ResilientEpisodeTrace(
-                **base_fields,
-                health_events=np.zeros((n, steps), np.uint8))
-        else:
-            out = EpisodeTrace(**base_fields)
+            base_fields = dict(
+                action_idx=np.zeros((n, steps, space.dim),
+                                    space.index_dtype()),
+                metrics=np.zeros((n, steps, k), np.float32),
+                rewards=np.zeros((n, steps), np.float32),
+                objectives=np.zeros((n, steps), np.float32),
+                restarts=np.zeros((n, steps), np.float32))
+            guarded = self.policy is not None
+            resilient = self.resilience is not None
+            if guarded:
+                from repro.core.guardrails import (
+                    GuardedCarry, GuardedEpisodeTrace)
+                guard = stack_np([s.guard for s in rows])
+                out = GuardedEpisodeTrace(
+                    **base_fields,
+                    guard_events=np.zeros((n, steps), np.uint8),
+                    shadow_objectives=np.zeros((n, steps), np.float32))
+            elif resilient:
+                from repro.core.resilience import (
+                    ResilientCarry, ResilientEpisodeTrace)
+                health = stack_np([s.health for s in rows])
+                out = ResilientEpisodeTrace(
+                    **base_fields,
+                    health_events=np.zeros((n, steps), np.uint8))
+            else:
+                out = EpisodeTrace(**base_fields)
 
-        fn = _compiled_episode(env0.model.step_fn, space, cfg,
-                               self._actor_tx, self._critic_tx, True,
-                               cfg.updates_per_step, fleet=True, devices=None,
-                               policy=self.policy, sharing=self.sharing,
-                               cell_size=cs, obs_mask=obs_mask,
-                               resilience=self.resilience)
-        peak = [live_device_bytes()]
+            fn = _compiled_episode(env0.model.step_fn, space, cfg,
+                                   self._actor_tx, self._critic_tx, True,
+                                   cfg.updates_per_step, fleet=True,
+                                   devices=None, policy=self.policy,
+                                   sharing=self.sharing, cell_size=cs,
+                                   obs_mask=obs_mask,
+                                   resilience=self.resilience)
         t0 = time.perf_counter()
 
         def stage(ci):
@@ -677,18 +715,13 @@ class FleetService:
             else:
                 xs = (chunk_of(use_warmup), chunk_of(warmup),
                       chunk_of(noise))
-            args = (chunk_of(params), chunk_of(w_vec), chunk_of(lo),
+            return (chunk_of(params), chunk_of(w_vec), chunk_of(lo),
                     chunk_of(span), carry, xs)
-            # sample peak while the staged operands are live — counts the
-            # in-flight transfer buffers the drain-side sample misses
-            peak[0] = max(peak[0], live_device_bytes())
-            return args
 
         def drain(ci, out_pair):
             carry, trace = out_pair
             a, b = ci * c, min(n, (ci + 1) * c)
             cnt = b - a
-            peak[0] = max(peak[0], live_device_bytes())
 
             def write_back(dst_tree, src_tree):
                 jax.tree_util.tree_map(
@@ -734,10 +767,11 @@ class FleetService:
             learn_keys[a:b] = np.asarray(carry.learn_key)[:cnt]
 
         staging_stats: dict = {}
-        stream_stats = stream_chunks(
-            lambda args: fn(*args), stage, drain, num_chunks,
-            overlap=self.overlap, supervisor=self.supervisor,
-            chaos=self.chaos, staging=staging_stats)
+        with phase("stream", self.counters):
+            stream_stats = stream_chunks(
+                lambda args: fn(*args), stage, drain, num_chunks,
+                overlap=self.overlap, supervisor=self.supervisor,
+                chaos=self.chaos, staging=staging_stats)
         wall = time.perf_counter() - t0
         failed_rows: set = set()
         quarantined: list = []
@@ -748,7 +782,7 @@ class FleetService:
                                   if primary_rows[j]})
         self.last_stats = dict(
             sessions=len(sessions), chunk=c, num_chunks=num_chunks,
-            steps=steps, overlap=self.overlap, peak_device_bytes=peak[0],
+            steps=steps, overlap=self.overlap,
             executable_cache_size=fn._cache_size(),
             session_steps_per_sec=len(sessions) * steps / max(wall, 1e-9),
             program=fn, cell_size=cs, sharing=self.sharing,
@@ -757,72 +791,73 @@ class FleetService:
             self.last_stats["supervisor"] = stream_stats
             self.last_stats["quarantined"] = list(quarantined)
 
-        # -- write per-session state + decision history back ----------------
-        per_step = wall / max(1, steps)
+        with phase("write_back", self.counters):
+            # -- write per-session state + decision history back ------------
+            per_step = wall / max(1, steps)
 
-        def row(tree, j):
-            return jax.tree_util.tree_map(lambda x: np.asarray(x[j]), tree)
+            def row(tree, j):
+                return jax.tree_util.tree_map(lambda x: np.asarray(x[j]), tree)
 
-        if shared_replay:
-            for g, cid in enumerate(sorted(self._cells)):
-                cb = self._cells[cid]["buf"]
-                for key, arr in zip(("s", "a", "r", "s2"), buf_np):
-                    cb[key] = np.asarray(arr[g])
-                cb["next"] = int(next_slots[g])
-                cb["size"] = int(sizes[g])
-        for cid in sorted(self._cells):
-            self._cells[cid]["steps"] += steps
-        if guarded:
-            from repro.core.guardrails import (
-                empty_counters, guardrail_counters, merge_counters)
-            round_counters = empty_counters()
-        if resilient:
-            from repro.core.resilience import (
-                empty_health_counters, health_counters,
-                merge_health_counters)
-        for j, s in enumerate(rows):
-            if not primary_rows[j]:
-                continue  # vacant-seat replica: everything discarded
-            if j in failed_rows:
-                # skipped chunk: the drain never ran, so the stacked arrays
-                # still hold this row's PRE-episode state and its trace rows
-                # are zeros — write nothing back; the session leaves with
-                # the state it had at the boundary
-                continue
-            if resilient:
-                s.health = row(health, j)
-                s.health_counters = merge_health_counters(
-                    s.health_counters or empty_health_counters(),
-                    health_counters(out.health_events[j]))
+            if shared_replay:
+                for g, cid in enumerate(sorted(self._cells)):
+                    cb = self._cells[cid]["buf"]
+                    for key, arr in zip(("s", "a", "r", "s2"), buf_np):
+                        cb[key] = np.asarray(arr[g])
+                    cb["next"] = int(next_slots[g])
+                    cb["size"] = int(sizes[g])
+            for cid in sorted(self._cells):
+                self._cells[cid]["steps"] += steps
             if guarded:
-                s.guard = row(guard, j)
-                delta = guardrail_counters(out.guard_events[j],
-                                           out.restarts[j])
-                s.guard_counters = merge_counters(
-                    s.guard_counters or empty_counters(), delta)
-                round_counters = merge_counters(round_counters, delta)
-            s.env.model_state = row(env_states, j)
-            s.ddpg = row(ddpg_states, j)
-            if not shared_replay:
-                for key, arr in zip(("s", "a", "r", "s2"), buf_np):
-                    s.buf[key] = np.asarray(arr[j])
-                s.buf["next"] = int(next_slots[j])
-                s.buf["size"] = int(sizes[j])
-            s.learn_key = np.asarray(learn_keys[j])
-            rep = replay_compact_trace(
-                s.env, out, j, start=len(s.history), per_step=per_step,
-                prev_config=s.cur_config, best_objective=s.best_objective,
-                restart_seconds=s.restart_seconds,
-                finite_baseline=resilient)
-            s.history.extend(rep["records"])
-            s.restart_seconds = rep["restart_seconds"]
-            if rep["best"] is not None:
-                s.best_objective = rep["best"]["objective"]
-                s.best_config = dict(rep["best"]["config"])
-                s.best_metrics = dict(rep["best"]["metrics"])
-            s.cur_config = rep["cur_config"]
-            if rep["cur_metrics"] is not None:
-                s.cur_metrics = rep["cur_metrics"]
+                from repro.core.guardrails import (
+                    empty_counters, guardrail_counters, merge_counters)
+                round_counters = empty_counters()
+            if resilient:
+                from repro.core.resilience import (
+                    empty_health_counters, health_counters,
+                    merge_health_counters)
+            for j, s in enumerate(rows):
+                if not primary_rows[j]:
+                    continue  # vacant-seat replica: everything discarded
+                if j in failed_rows:
+                    # skipped chunk: the drain never ran, so the stacked arrays
+                    # still hold this row's PRE-episode state and its trace
+                    # rows are zeros — write nothing back; the session leaves
+                    # with the state it had at the boundary
+                    continue
+                if resilient:
+                    s.health = row(health, j)
+                    s.health_counters = merge_health_counters(
+                        s.health_counters or empty_health_counters(),
+                        health_counters(out.health_events[j]))
+                if guarded:
+                    s.guard = row(guard, j)
+                    delta = guardrail_counters(out.guard_events[j],
+                                               out.restarts[j])
+                    s.guard_counters = merge_counters(
+                        s.guard_counters or empty_counters(), delta)
+                    round_counters = merge_counters(round_counters, delta)
+                s.env.model_state = row(env_states, j)
+                s.ddpg = row(ddpg_states, j)
+                if not shared_replay:
+                    for key, arr in zip(("s", "a", "r", "s2"), buf_np):
+                        s.buf[key] = np.asarray(arr[j])
+                    s.buf["next"] = int(next_slots[j])
+                    s.buf["size"] = int(sizes[j])
+                s.learn_key = np.asarray(learn_keys[j])
+                rep = replay_compact_trace(
+                    s.env, out, j, start=len(s.history), per_step=per_step,
+                    prev_config=s.cur_config, best_objective=s.best_objective,
+                    restart_seconds=s.restart_seconds,
+                    finite_baseline=resilient)
+                s.history.extend(rep["records"])
+                s.restart_seconds = rep["restart_seconds"]
+                if rep["best"] is not None:
+                    s.best_objective = rep["best"]["objective"]
+                    s.best_config = dict(rep["best"]["config"])
+                    s.best_metrics = dict(rep["best"]["metrics"])
+                s.cur_config = rep["cur_config"]
+                if rep["cur_metrics"] is not None:
+                    s.cur_metrics = rep["cur_metrics"]
         if guarded:  # this round's fleet-aggregate guardrail counters
             self.last_stats["guardrails"] = round_counters
         return quarantined

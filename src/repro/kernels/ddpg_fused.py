@@ -401,6 +401,7 @@ def ddpg_fused_learn(packed, batches, *, dims: PackedDims, gamma: float,
         input_output_aliases={5: 0, 6: 1, 7: 2, 8: 3},
         cost_estimate=cost,
         interpret=interpret,
+        name="ddpg_fused_learn",
     )(cnt, sx, cx, s2x, rx, weights, biases, mom_w, mom_b)
     met = met.reshape(n, u, 3)
     metrics = {"critic_loss": met[..., 0], "actor_loss": met[..., 1],
