@@ -3,7 +3,7 @@
 Drives the main path once through the entry points a user calls, at the
 service's real widths, and checks what comes out:
 
-  python chip_smoke.py            # one chip: learner kernel, then the service
+  python chip_smoke.py            # one chip: both learners, then the service
   python chip_smoke.py --chips 4  # only the sharded session axis, 4 chips
 
 Run it from the repository root; it puts ``src`` on ``sys.path`` itself.
@@ -37,7 +37,8 @@ ROUND_STEPS = 10
 WORKLOADS = ("file_server", "video_server", "seq_write", "seq_read",
              "random_rw")
 OBJECTIVES = ({"throughput": 1.0}, {"throughput": 1.0, "iops": 1.0})
-# Pallas learner vs XLA learner after one 96-update call from a fresh init.
+# Pallas learner vs XLA learner (the one ``auto`` runs) after one 96-update
+# call from a fresh init.
 # Both run every matmul at Precision.HIGHEST; what remains is f32 rounding
 # order and transcendental implementations. Over 96 updates Adam turns a
 # rounding-level sign change of a near-zero gradient into a step of size
@@ -131,7 +132,8 @@ def _float_gap(a_tree, b_tree, atol, rtol):
 
 def phase_learner() -> None:
     """One fleet learn call on a chunk of magpie8 sessions, through the
-    resolved ``auto`` learner and through the XLA ``ddpg_learn_scan``."""
+    learner ``auto`` resolves to (the XLA scan) and through the Pallas
+    kernel, each timed cold and warm."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -159,40 +161,40 @@ def phase_learner() -> None:
 
     with _kernels("auto"):
         mode = ops.ddpg_kernel_mode() or "xla"
-        auto_out, auto_s = _timed(learn)
-        _, auto_warm_s = _timed(learn)
-    with _kernels("xla"):
         xla_out, xla_s = _timed(learn)
         _, xla_warm_s = _timed(learn)
+    with _kernels("pallas"):
+        pallas_out, pallas_s = _timed(learn)
+        _, pallas_warm_s = _timed(learn)
     _log(f"learner: resolved auto mode = {mode}; {n} sessions x {u} updates "
          f"x batch {cfg.batch_size}, state {k}, action {m}")
-    _log(f"learner: auto first call (compile + run) {auto_s:.3f} s, "
-         f"second call {auto_warm_s:.4f} s; xla first call {xla_s:.3f} s, "
-         f"second call {xla_warm_s:.4f} s")
-    _check(mode == "pallas",
-           f"auto resolved the learner to {mode!r}, not the Pallas kernel")
-    a_state, x_state = auto_out[0], xla_out[0]
-    for name, st in (("auto", a_state), ("xla", x_state)):
+    _log(f"learner: auto (xla) first call (compile + run) {xla_s:.3f} s, "
+         f"second call {xla_warm_s:.4f} s; pallas first call "
+         f"{pallas_s:.3f} s, second call {pallas_warm_s:.4f} s")
+    _check(mode == "xla",
+           f"auto resolved the learner to {mode!r}, not the XLA learner")
+    p_state, x_state = pallas_out[0], xla_out[0]
+    for name, st in (("pallas", p_state), ("xla", x_state)):
         _check(int(np.min(st.step)) == int(np.max(st.step)) == u,
                f"{name} learner step is not {u}")
         _check(int(np.min(st.actor_opt[0].count)) == u
                and int(np.min(st.critic_opt[0].count)) == u,
                f"{name} Adam counts did not advance by {u}")
     ints_equal, worst, outside, where, q99 = _float_gap(
-        auto_out, xla_out, LEARNER_ATOL, LEARNER_RTOL)
+        pallas_out, xla_out, LEARNER_ATOL, LEARNER_RTOL)
     finite = all(bool(np.all(np.isfinite(np.asarray(x))))
-                 for x in jax.tree_util.tree_leaves(auto_out))
+                 for x in jax.tree_util.tree_leaves((pallas_out, xla_out)))
     _log(f"learner: Adam counts and step equal: {ints_equal}; over params, "
-         f"moments and metrics: max |auto - xla| {worst:.3e}, largest "
+         f"moments and metrics: max |pallas - xla| {worst:.3e}, largest "
          f"per-leaf 99th percentile {q99:.3e}, largest per-leaf share "
          f"outside {LEARNER_ATOL:g} + {LEARNER_RTOL:g}*|xla| "
          f"{outside * 100:.3f}% at {where} (limit "
          f"{LEARNER_MAX_OUTSIDE * 100:g}%); finite: {finite}")
-    _check(ints_equal, "Adam counts / step differ between auto and xla")
-    _check(finite, "the auto learner produced non-finite values")
+    _check(ints_equal, "Adam counts / step differ between pallas and xla")
+    _check(finite, "a learner produced non-finite values")
     _check(outside <= LEARNER_MAX_OUTSIDE,
            f"{outside * 100:.3f}% of a leaf's elements differ between the "
-           f"auto and xla learners by more than the tolerance")
+           f"pallas and xla learners by more than the tolerance")
 
 
 def phase_service() -> None:

@@ -252,7 +252,11 @@ def _learn_packed(state, batches, cfg, num_updates, mode="pallas"):
     """Route one session's pre-gathered inner loop through the fused-kernel
     dispatch (``kernels.ops.ddpg_inner_loop``), packing the learner state
     into the [P, P]-blocked VMEM layout and back. vmap-safe: under the fleet
-    vmap the kernel's session grid batches automatically."""
+    vmap the kernel's grid runs one session per step. Only
+    ``REPRO_KERNELS=pallas`` / ``interpret`` route here; ``auto`` keeps the
+    scan over ``_ddpg_step`` on every platform, which the fleet vmap turns
+    into session-batched dots (faster on a TPU, see
+    ``kernels.ops.ddpg_kernel_mode``)."""
     from repro.kernels import ddpg_fused as fused
     from repro.kernels import ops
     from repro.optim.transform import ScaleByAdamState

@@ -39,9 +39,14 @@ in the padding forever — pinned by tests/test_ddpg_fused.py.
 The same packed update step (``packed_update``) is also compiled directly by
 XLA (``ddpg_fused_xla``) — that is the "fleet-batched GEMM" formulation of
 the fallback. On CPU the blocked [P, P] GEMMs lose to the unpadded scan
-(see benchmarks/fleet_throughput.py::bench_learner_paths), so the CPU
-default stays ``core.ddpg``'s pre-gathered scan; the packed path is the
-kernel's oracle-validated twin and the TPU shape of the computation.
+(see benchmarks/fleet_throughput.py::bench_learner_paths); on a TPU the
+kernel loses too, since its grid runs one session's 96 dependent updates
+per step and nothing from another session fills the MXU while they wait
+(121.5 ms against the vmapped scan's 45.7 ms, 256 magpie8 sessions x 96
+updates on a v5e). So ``REPRO_KERNELS=auto`` runs ``core.ddpg``'s
+pre-gathered scan on every platform; this kernel runs only under
+``pallas`` / ``interpret``, and the packed path is its oracle-validated
+twin.
 
 Adam hyperparameters are ``repro.optim.adam``'s defaults (b1=0.9, b2=0.999,
 eps=1e-8) — the only transforms ``core.ddpg`` ever builds; the dispatcher

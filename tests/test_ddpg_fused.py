@@ -238,6 +238,50 @@ def test_fleet_kernel_grid_matches_xla(monkeypatch):
     _assert_learner_close(k_states, x_states)
 
 
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("kernels", [None, "auto", "xla", "pallas",
+                                     "interpret"])
+def test_learner_mode_resolution(platform, kernels, monkeypatch):
+    """``auto`` (or unset) runs the XLA learner on every platform, a TPU
+    included; ``pallas`` and ``interpret`` name the kernel anywhere. The
+    other kernels' ``auto`` still picks Pallas on a TPU."""
+    from repro.kernels import ops
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if kernels is None:
+        monkeypatch.delenv("REPRO_KERNELS", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_KERNELS", kernels)
+    explicit = kernels in ("pallas", "interpret")
+    assert ops.ddpg_kernel_mode() == (kernels if explicit else None)
+    if kernels in (None, "auto"):
+        assert ops._mode() == ("pallas" if platform == "tpu" else "xla")
+    else:
+        assert ops._mode() == kernels
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_fleet_learn_auto_is_the_xla_program(platform, monkeypatch):
+    """Under ``auto`` the fleet learner is the program ``xla`` runs, on a
+    TPU too: bitwise the same states and metrics."""
+    cfg = DDPGConfig(state_dim=12, action_dim=8)
+    n = 3
+    states, (atx, ctx) = fleet_init(
+        jnp.stack([jax.random.PRNGKey(s) for s in range(n)]), cfg)
+    rng = np.random.default_rng(4)
+    data = tuple(np.stack(xs) for xs in zip(
+        *[_storage(rng, 16, 12, 8) for _ in range(n)]))
+    sizes = jnp.full((n,), 12, jnp.int32)
+    lkeys = jnp.stack([jax.random.PRNGKey(s + 5) for s in range(n)])
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+
+    monkeypatch.setenv("REPRO_KERNELS", "xla")
+    x_out = fleet_learn_scan(states, data, sizes, lkeys, cfg, atx, ctx, 6)
+    monkeypatch.setenv("REPRO_KERNELS", "auto")
+    a_out = fleet_learn_scan(states, data, sizes, lkeys, cfg, atx, ctx, 6)
+    assert int(np.min(a_out[0].step)) == 6
+    assert _max_ulp(a_out, x_out) == 0
+
+
 def test_padded_lanes_stay_zero():
     """Zero padding is a fixed point of the whole inner loop: weights, Adam
     moments and Polyak targets keep exact zeros in every padded row/column

@@ -1,5 +1,6 @@
-"""Compile-only guards for the TPU: the main path's learner kernel and its
-chunk episode program, compiled for a described TPU v5e (``v5e:2x2``) by
+"""Compile-only guards for the TPU: the learner kernel and the chunk
+episode program the service runs, with either learner, compiled for a
+described TPU v5e (``v5e:2x2``) by
 the TPU compiler that ships with libtpu. Nothing runs and no chip is
 needed; a kernel the chip's compiler would refuse fails here.
 
@@ -81,13 +82,17 @@ def test_learner_kernel_compiles_for_v5e(state_dim, action_dim, one_chip,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_chunk_episode_compiles_for_v5e(one_chip, no_persistent_cache,
-                                        monkeypatch):
+@pytest.mark.parametrize("kernels,mosaic", [("auto", False),
+                                            ("pallas", True)])
+def test_chunk_episode_compiles_for_v5e(kernels, mosaic, one_chip,
+                                        no_persistent_cache, monkeypatch):
     """The jitted chunk episode the service runs (magpie8, chunk 256), with
-    the learner mode that ``auto`` picks on a TPU."""
-    mode = ops.auto_mode("tpu")
-    assert mode == "pallas"
-    monkeypatch.setenv("REPRO_KERNELS", mode)
+    the learner ``REPRO_KERNELS=kernels`` resolves to on a TPU: ``auto``
+    runs the XLA learner, so the program holds no Mosaic call; ``pallas``
+    puts the Pallas learner kernel inside."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_KERNELS", kernels)
+    assert ops.ddpg_kernel_mode() == ("pallas" if mosaic else None)
     env = LustreSimV2("seq_write", seed=0).to_model_env()
     cfg = DDPGConfig.for_env(env)
     agent = FleetAgent(cfg, [0], buffer_capacity=64, warmup_steps=8,
@@ -101,4 +106,4 @@ def test_chunk_episode_compiles_for_v5e(one_chip, no_persistent_cache,
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
         shapes)
     compiled = episode.lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()  # the Pallas learner
+    assert ("tpu_custom_call" in compiled.as_text()) == mosaic
