@@ -6,6 +6,18 @@ metric is found by name: ``bench/configs/<config>.json``,
 ``bench/mixes/<traffic>.json``, ``bench/metrics/<metric>.py`` and
 ``bench/limits/<workload>.json``, all named in ``BENCHMARK.json``. A new cell
 is a new entry there plus new files; no code here names a cell.
+
+A cell's ``chips`` reach the service (``_service``). A cell on one chip
+builds ``FleetService`` from its configuration's keyword arguments alone,
+on the default device. A cell on more chips hands the service its first
+``chips`` devices as ``devices=``, the keyword ``FleetTuner.from_grid`` and
+``run_fleet_episode_scan`` take, and keeps the configuration's ``chunk``:
+a multi-chip configuration states its chunk across all of its chips, as
+``resolve_chunk(n, chunk, num_devices)`` reads it. Where ``FleetService``
+takes no ``devices``, such a cell stops with a ``BenchError`` before any
+session joins; it never runs on one chip under a multi-chip label. So a
+multi-chip deployment is data alone: its configuration, its
+``BENCHMARK.json`` entry and its limits file.
 """
 
 from __future__ import annotations
@@ -13,6 +25,7 @@ from __future__ import annotations
 import argparse
 import gc
 import importlib.util
+import inspect
 import json
 import os
 import shutil
@@ -142,14 +155,22 @@ class GcPauses:
 
 # -- the run -------------------------------------------------------------------
 
-def _service(config: dict):
+def _service(config: dict, devices):
+    """The cell's ``FleetService`` on the cell's ``devices`` (the module's
+    docstring gives the contract)."""
     from repro.core.service import FleetService
     from repro import envs
-    return FleetService(
+    kwargs = dict(
         chunk=int(config["chunk"]), env_cls=getattr(envs, config["env"]),
         buffer_capacity=int(config["buffer_capacity"]),
         warmup_steps=int(config["warmup_steps"]),
         eval_runs=int(config["eval_runs"]))
+    if len(devices) > 1:
+        if "devices" not in inspect.signature(FleetService).parameters:
+            raise BenchError(f"FleetService takes no devices=, so the "
+                             f"service cannot run on {len(devices)} chips")
+        kwargs["devices"] = tuple(devices)
+    return FleetService(**kwargs)
 
 
 def _check_widths(svc, config: dict) -> None:
@@ -239,7 +260,7 @@ def measure(argv=None, *, t_start: float, require_tpu: bool = True,
 
     rng = np.random.default_rng(args.seed)
     roster = traffic.Roster(config["workloads"], config["objectives"], rng)
-    svc = _service(config)
+    svc = _service(config, used)
     fleet = int(config["sessions"])
     client = traffic.Client(svc, roster, mix, fleet)
     client.populate()
@@ -299,12 +320,17 @@ def measure(argv=None, *, t_start: float, require_tpu: bool = True,
 
     metrics, breakdown = {}, None
     if args.trace:
-        from bench import flops, tracing
+        from bench import flops, learner, tracing
         tr = tracing.read_xplane(tracing.latest_xplane(trace_dir))
         shutil.rmtree(trace_dir, ignore_errors=True)
         busy = tracing.busy_seconds(tr)
         device.update(busy_s=busy, window_s=tr.window_s)
         breakdown = tracing.breakdown(tr)
+        n_un, s_un = learner.unattributed(tr)
+        log(f"scopes: the chunk program {learner.episode_seconds(tr)!r} "
+            f"device s, its learn scope {learner.kernel_seconds(tr)!r} s; "
+            f"{n_un} of its ops unattributed, {s_un!r} s; leaf ops by "
+            f"scope {learner.scope_leaf_seconds(tr)!r}")
         ctx = MetricContext(trace=tr, rounds=rounds, spans=client.spans,
                             config=config, peaks=peaks, chips=len(used),
                             steps_per_s=steps_per_s, flops=flops,

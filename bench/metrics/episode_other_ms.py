@@ -1,6 +1,8 @@
 """episode_other_ms: device milliseconds per chunk-step of the chunk
 program outside the learner (layer: chunk program: act, env response,
-reward, replay store), in the traced window."""
+reward, replay store and the loop around them), in the traced window: the
+chunk program's device-busy time less the learner's ``learn`` scope
+(``bench/learner.py``)."""
 
 
 def read(ctx):

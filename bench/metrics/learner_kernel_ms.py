@@ -1,7 +1,9 @@
 """learner_kernel_ms: device milliseconds of the learner per chunk-step
-(layer: learner), summed over the learner's device events in the traced
-window. Today the learner is the Pallas kernel of ``kernels/ddpg_fused.py``,
-found by the name the trace gives it (``bench/learner.py``)."""
+(layer: learner): the union, in the traced window, of the chunk program's
+ops under the program's ``learn`` scope (``bench/learner.py``). Under
+``auto`` that is XLA's session-batched scan of the 96 updates; under
+``REPRO_KERNELS=pallas`` the ``ddpg_fused_learn`` kernel; on both with
+the minibatch gathers from the replay buffer."""
 
 
 def read(ctx):

@@ -3,9 +3,10 @@
 learner work, the larger of its operations over the bf16 peak and its
 bytes over the HBM peak (``bench/flops.py``, unpadded: one call of the
 configured updates per session-step delivered), over the learner's device
-time in the traced window. At both configurations' widths the operations
-bound it (magpie8: 0.904 us of bf16 peak against 0.680 us of HBM per
-session's call)."""
+time in the traced window: the union of the chunk program's ops under the
+``learn`` scope (``bench/learner.py``), whichever path runs the learner.
+At both configurations' widths the operations bound it (magpie8: 0.904 us
+of bf16 peak against 0.680 us of HBM per session's call)."""
 
 
 def read(ctx):

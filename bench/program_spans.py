@@ -241,7 +241,8 @@ def run(argv=None, *, require_tpu: bool = True) -> dict:
 
     roster = traffic.Roster(config["workloads"], config["objectives"],
                             np.random.default_rng(args.seed))
-    svc = harness._service(config)
+    svc = harness._service(config,
+                           jax.devices()[:int(parts["cell"]["chips"])])
     client = traffic.Client(svc, roster, mix, fleet)
     t0 = time.perf_counter()
     client.populate()

@@ -85,3 +85,65 @@ def test_no_tpu_no_result():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "no TPU" in proc.stderr
+
+
+# -- a cell's chips reach the service ------------------------------------------
+
+def _stub_service(monkeypatch, takes_devices: bool) -> list:
+    """Put a stand-in for ``FleetService`` in the program's place; the
+    keyword arguments of every service built land in the returned list."""
+    from repro.core import service
+
+    built = []
+
+    if takes_devices:
+        class Stub:
+            def __init__(self, *, chunk, env_cls, buffer_capacity,
+                         warmup_steps, eval_runs, devices=None):
+                built.append(dict(chunk=chunk, env_cls=env_cls,
+                                  buffer_capacity=buffer_capacity,
+                                  warmup_steps=warmup_steps,
+                                  eval_runs=eval_runs, devices=devices))
+    else:
+        class Stub:
+            def __init__(self, **kwargs):
+                built.append(kwargs)
+
+    monkeypatch.setattr(service, "FleetService", Stub)
+    return built
+
+
+def _config(name="magpie8_1024"):
+    return harness.load_json(os.path.join(ROOT, "bench", "configs",
+                                          name + ".json"))
+
+
+@pytest.mark.parametrize("takes_devices", [False, True])
+def test_one_chip_builds_the_service_as_before(monkeypatch, takes_devices):
+    """One chip: the configuration's keyword arguments and no ``devices``,
+    whether the service takes them or not."""
+    from repro import envs
+    built = _stub_service(monkeypatch, takes_devices)
+    config = _config()
+    harness._service(config, ["chip0"])
+    want = dict(chunk=256, env_cls=envs.LustreSimV2, buffer_capacity=64,
+                warmup_steps=8, eval_runs=3)
+    if takes_devices:
+        want["devices"] = None          # left at the service's default
+    assert built == [want]
+
+
+def test_four_chips_hand_the_service_its_devices(monkeypatch):
+    built = _stub_service(monkeypatch, takes_devices=True)
+    chips = ["chip0", "chip1", "chip2", "chip3"]
+    harness._service(_config(), chips)
+    assert built[0]["devices"] == tuple(chips)
+    assert built[0]["chunk"] == 256     # the chunk across all the chips
+
+
+def test_four_chips_stop_where_the_service_takes_no_devices(monkeypatch):
+    built = _stub_service(monkeypatch, takes_devices=False)
+    with pytest.raises(harness.BenchError, match="cannot run on 4 chips"):
+        harness._service(_config(), ["chip0", "chip1", "chip2", "chip3"])
+    assert built == []
+

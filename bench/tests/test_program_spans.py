@@ -117,9 +117,14 @@ def test_recorded_program_spans(recorded):
         if s.name == "prepare":
             assert program_spans.innermost(
                 main, 0.5 * (s.start + s.end)).name == "prepare"
-    # the learner kernel, named now, is still found by its target
-    kernel = [e for e in trace.ops() if learner.LEARNER_EVENT in e.name]
-    assert len(kernel) == 2
+    # the Pallas learner, named now, lies in the program's learn scope
+    kernel = [e for e in trace.ops()
+              if tracing.short_name(e.name).endswith("tpu_custom_call")]
+    assert [tracing.short_name(e.name) for e in kernel] == [
+        "%ddpg_fused_learn.2 tpu_custom_call"] * 2
+    assert {e.scope for e in kernel} == {"learn"}
+    assert kernel[0].op_name.endswith("/learn/ddpg_fused_learn/pallas_call")
+    assert learner.kernel_seconds(trace) > sum(e.seconds for e in kernel)
     assert counters["rounds"] >= 2 and counters["joins"] >= 10
 
 

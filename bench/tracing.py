@@ -3,10 +3,13 @@
 A traced run records a short window with ``jax.profiler`` (the Python
 tracer off, so the host pays only for the benchmark's own
 ``TraceAnnotation`` spans) and reduces the ``.xplane.pb`` it writes with
-nothing but ``jax.profiler.ProfileData``:
+``jax.profiler.ProfileData`` and, for the HLO it carries, ``bench/xspace.py``:
 
   * device operations: every event on the ``XLA Ops`` line of each
-    ``/device:TPU:<n>`` plane, with its name, start and duration;
+    ``/device:TPU:<n>`` plane, with its name, start and duration, its
+    program (the ``XLA Modules`` event it falls in) and its named scope:
+    the first of ``SCOPES`` in its HLO instruction's ``op_name``, which
+    ``bench/xspace.py`` reads from the HLO the profile carries;
   * device busy time: the union of those intervals inside the window;
   * host spans: the benchmark's ``bench.<kind>`` annotations on the host
     plane, on the same clock as the device events;
@@ -26,6 +29,8 @@ SPAN_PREFIX = "bench."
 DEVICE_PLANE_PREFIX = "/device:TPU:"
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
+# the program's own scopes (``jax.named_scope`` in core/episode.py)
+SCOPES = ("act", "env", "reward", "store", "learn")
 
 
 @dataclasses.dataclass
@@ -34,6 +39,10 @@ class Event:
     start: float     # seconds on the trace clock
     end: float
     module: str = ""  # the XLA module (program) the op belongs to
+    # the op's HLO instruction's metadata op_name; None where the module's
+    # HLO in the profile does not hold the instruction (unattributed)
+    op_name: Optional[str] = None
+    scope: str = ""   # the first of SCOPES in op_name, else ""
 
     @property
     def seconds(self) -> float:
@@ -80,8 +89,10 @@ def read_xplane(path: str) -> Trace:
     """Device ops and benchmark spans of one profile. The window runs from
     the first span's start to the last span's end."""
     from jax.profiler import ProfileData
+    from bench import xspace
 
     data = ProfileData.from_file(path)
+    hlo = xspace.op_names(path)
     devices, spans = {}, []
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PLANE_PREFIX):
@@ -95,6 +106,7 @@ def read_xplane(path: str) -> Trace:
                                _stat(e, "hlo_module") or "")
                     (evs if line.name == OPS_LINE else modules).append(ev)
             _attribute_modules(evs, modules)
+            _attribute_scopes(evs, hlo)
             devices[plane.name] = sorted(evs, key=lambda e: e.start)
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -121,6 +133,26 @@ def _attribute_modules(ops: list, modules: list) -> None:
         i = bisect.bisect_right(starts, op.start) - 1
         if i >= 0 and op.start < modules[i].end:
             op.module = modules[i].name
+
+
+def instruction_name(name: str) -> str:
+    """The HLO instruction of an op's event: ``fusion.611`` for
+    ``%fusion.611 = f32[...] fusion(...)``."""
+    return name.split(" = ", 1)[0].strip().lstrip("%")
+
+
+def scope_of(op_name: str) -> str:
+    """The outermost of ``SCOPES`` on an ``op_name``'s path, else ""."""
+    return next((p for p in op_name.split("/") if p in SCOPES), "")
+
+
+def _attribute_scopes(ops: list, hlo: dict) -> None:
+    """Each op's ``op_name`` and ``scope``, by its module and instruction
+    name in ``hlo`` ({module: {instruction: op_name}})."""
+    for op in ops:
+        op_name = hlo.get(op.module, {}).get(instruction_name(op.name))
+        if op_name is not None:
+            op.op_name, op.scope = op_name, scope_of(op_name)
 
 
 def union_seconds(intervals, lo: float, hi: float) -> float:
